@@ -685,7 +685,7 @@ def profile_cell(n: int, T: int, est, prof, table, cfg, fixed, rng,
     from repro.estimator.train import fwd
     from repro.kernels.featurize import kpm_feature_windows
     from repro.sim.cells import coupled_interference_mw
-    from repro.sim.engine import (EST_CHUNK_ROWS, run_controllers,
+    from repro.sim.engine import (est_chunk_rows, run_controllers,
                                   run_scheduled)
 
     ecfg, params = est
@@ -710,10 +710,10 @@ def profile_cell(n: int, T: int, est, prof, table, cfg, fixed, rng,
     stages["featurize_host"] = _best_of(host_feat)
     stages["featurize_fused"] = _best_of(fused_feat)
 
-    # estimator forward: one EST_CHUNK_ROWS-row dispatch, fp32 vs int8
+    # estimator forward: one est_chunk_rows-row dispatch, fp32 vs int8
     # (exactly the rows the engine's chunked estimate_fleet builds)
     wins = ep.kpm_windows(normalize=True).astype(np.float32)
-    b = max(1, min(T, EST_CHUNK_ROWS // max(n, 1)))
+    b = max(1, min(T, est_chunk_rows(ecfg) // max(n, 1)))
     kpms_rows = jnp.asarray(np.ascontiguousarray(wins[:, :b]).reshape(
         n * b, *wins.shape[2:]))
     iq_rows = jnp.asarray(np.asarray(ep.iq[:, :b], np.float32).reshape(
@@ -1285,4 +1285,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -24,7 +24,10 @@ from repro.core.energy import EDGE_A40X2, UE_VM_2CORE
 from repro.core.objective import Constraints, Weights
 from repro.core.pso import pso_vectorized
 from repro.core.splitting import vgg_head, vgg_tail
+from repro.launch.cache import enable_compile_cache
 from repro.models.vgg import FULL, REDUCED, init_vgg, vgg_split_profile
+
+enable_compile_cache()
 
 # 1. profile -------------------------------------------------------------
 profile = vgg_split_profile(FULL)

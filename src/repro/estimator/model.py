@@ -36,6 +36,17 @@ class EstimatorConfig:
     def cnn_flat(self) -> int:
         return self.cnn_ch[1] * (self.n_sc // 4) * (self.n_sym // 4)
 
+    @property
+    def row_bytes(self) -> int:
+        """f32 bytes one input row holds at the forward's peak: the
+        (2, n_sc, n_sym) IQ input, conv1's full-resolution activations,
+        conv2's (after one 2x2 pool) and the (window, n_kpms) KPM window
+        — what ``cnn_branch`` and ``lstm_branch`` below allocate."""
+        px = self.n_sc * self.n_sym
+        c1, c2 = self.cnn_ch
+        return 4 * (2 * px + px * c1 + (px // 4) * c2
+                    + self.window * self.n_kpms)
+
 
 def estimator_template(e: EstimatorConfig):
     c1, c2 = e.cnn_ch

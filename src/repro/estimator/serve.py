@@ -44,7 +44,7 @@ def check_quant(quant) -> None:
 
 
 def quantize_estimator(params, *, use_kernel: bool = True,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """fp32 estimator params -> the int8 serving tree.
 
     Every dense matmul weight (LSTM input/recurrent, LSTM projection, CNN
@@ -80,7 +80,8 @@ def _conv_trunk(p, iq):
 
 
 def estimator_forward_int8(e: EstimatorConfig, qparams, kpms, iq, alloc, *,
-                           use_kernel: bool = True, interpret: bool = True):
+                           use_kernel: bool = True,
+                           interpret: bool | None = None):
     """The serving forward on a ``quantize_estimator`` tree: (B,) Mbps.
 
     Mirrors ``model.estimator_forward`` (inference mode) with every dense
@@ -107,7 +108,7 @@ def estimator_forward_int8(e: EstimatorConfig, qparams, kpms, iq, alloc, *,
 @partial(jax.jit, static_argnums=0,
          static_argnames=("use_kernel", "interpret"))
 def fwd_int8(e, qparams, kpms, iq, alloc, *, use_kernel=True,
-             interpret=True):
+             interpret=None):
     """One jitted int8 inference forward (the ``estimator.train.fwd``
     counterpart the fused engine path calls per chunk)."""
     return estimator_forward_int8(e, qparams, kpms, iq, alloc,
@@ -116,7 +117,7 @@ def fwd_int8(e, qparams, kpms, iq, alloc, *, use_kernel=True,
 
 def predict_int8(e: EstimatorConfig, qparams, data: dict,
                  batch: int | None = 64, *, use_kernel: bool = True,
-                 interpret: bool = True) -> np.ndarray:
+                 interpret: bool | None = None) -> np.ndarray:
     """int8 twin of ``estimator.train.predict`` — Mbps for every row."""
     outs = []
     n = len(data["tp"])

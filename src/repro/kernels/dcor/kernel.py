@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 
 
@@ -47,7 +49,7 @@ def _dist_kernel(xi_ref, xj_ref, o_ref, acc_ref, *, n_d):
 
 
 def pairwise_dists(x, *, block_n: int = 128, block_d: int = 512,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     n, d = x.shape
     block_n = min(block_n, n)
     block_d = min(block_d, d)
@@ -68,6 +70,6 @@ def pairwise_dists(x, *, block_n: int = 128, block_d: int = 512,
         out_specs=pl.BlockSpec((block_n, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, np_), F32),
         scratch_shapes=[pltpu.VMEM((block_n, block_n), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, x)
     return out[:n, :n]

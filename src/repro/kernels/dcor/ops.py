@@ -10,6 +10,6 @@ from repro.kernels.dcor.kernel import pairwise_dists
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def dcor_kernel(x, y, *, interpret: bool = True):
+def dcor_kernel(x, y, *, interpret: bool | None = None):
     fn = partial(pairwise_dists, interpret=interpret)
     return _dcor(x, y, dist_fn=fn)

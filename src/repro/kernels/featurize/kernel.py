@@ -12,8 +12,10 @@ host->device boundary once, at 1/WINDOW the bytes.
 
 Grid: (row blocks, window blocks). Every grid step sees the full trace
 axis (the overlapping windows make block-aligned input tiling impossible)
-and slices its windows out with dynamic starts; the window axis itself is
-a static WINDOW-step unroll of contiguous copies.
+and loads each of its windows straight from the input ref at a dynamic
+start (``pl.ds``): Mosaic lowers a ref slice, not a ``dynamic_slice`` of a
+loaded value. The window axis is a static unroll of ``block_windows``
+(window, K) copies, each stored whole into the output block.
 """
 from __future__ import annotations
 
@@ -23,25 +25,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 
 
 def _featurize_kernel(x_ref, c_ref, s_ref, o_ref, *, block_b, window):
     j = pl.program_id(1)
-    # normalize once per grid step; the division (not a reciprocal
-    # multiply) mirrors channel.kpm.normalize_kpms so kernel, oracle and
-    # host path agree to f32 rounding
-    xn = (x_ref[...].astype(F32) - c_ref[...]) / s_ref[...]
-    for w in range(window):  # static unroll: WINDOW contiguous copies
-        o_ref[:, :, w, :] = jax.lax.dynamic_slice_in_dim(
-            xn, j * block_b + w, block_b, axis=1)
+    for b in range(block_b):  # static unroll: one window per copy
+        x = x_ref[:, pl.ds(j * block_b + b, window), :].astype(F32)
+        # the division (not a reciprocal multiply) mirrors
+        # channel.kpm.normalize_kpms so kernel, oracle and host path agree
+        # to f32 rounding
+        o_ref[:, b, :, :] = (x - c_ref[...]) / s_ref[...]
 
 
-def featurize(kpms, center, scale, window: int, *, block_rows: int = 128,
-              block_windows: int = 32, interpret: bool = True):
+def featurize(kpms, center, scale, window: int, *, block_rows: int = 16,
+              block_windows: int = 8, interpret: bool | None = None):
     """kpms (N, L, K) raw -> (N, B, window, K) normalized windows, where
     ``B = L - window + 1`` and window ``b`` covers trace steps
-    ``[b, b + window)`` — the ``EpisodeBatch.kpm_windows`` convention."""
+    ``[b, b + window)`` — the ``EpisodeBatch.kpm_windows`` convention.
+
+    The default blocks keep the output tile — (window, K) pads to
+    (32, 128) f32 in VMEM — at 2 MiB, well inside scoped VMEM."""
     n, length, k = kpms.shape
     b = length - window + 1
     if b < 1:
@@ -58,8 +64,8 @@ def featurize(kpms, center, scale, window: int, *, block_rows: int = 128,
         grid=(npad // bn, bpad // bb),
         in_specs=[
             # full trace axis per step: the windows overlap, so their
-            # source range is not block-alignable — each step dynamic-
-            # slices its own span out of the shared slab
+            # source range is not block-alignable — each step slices its
+            # own span out of the shared slab
             pl.BlockSpec((bn, length + pad_b, k), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, k), lambda i, j: (0, 0)),
             pl.BlockSpec((1, k), lambda i, j: (0, 0)),
@@ -67,7 +73,7 @@ def featurize(kpms, center, scale, window: int, *, block_rows: int = 128,
         out_specs=pl.BlockSpec((bn, bb, window, k),
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, bpad, window, k), F32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kpms, jnp.asarray(center, F32).reshape(1, k),
       jnp.asarray(scale, F32).reshape(1, k))
     return out[:n, :b]

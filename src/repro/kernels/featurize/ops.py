@@ -11,7 +11,8 @@ from repro.kernels.featurize.ref import featurize_ref
 
 @partial(jax.jit, static_argnames=("window", "use_kernel", "interpret"))
 def kpm_feature_windows(kpms, center, scale, window: int, *,
-                        use_kernel: bool = True, interpret: bool = True):
+                        use_kernel: bool = True,
+                        interpret: bool | None = None):
     """(N, L, K) raw KPM slab -> (N, L - window + 1, window, K) normalized
     rolling windows, entirely on device.
 

@@ -59,7 +59,7 @@ def _flatten_gqa(q, k, v):
 @partial(jax.jit, static_argnames=("causal", "window", "use_kernel",
                                    "block_q", "block_k", "interpret"))
 def mha(q, k, v, *, causal=True, window=0, use_kernel=True, block_q=128,
-        block_k=128, interpret=True):
+        block_k=128, interpret=None):
     """Multi-head attention. q: (B,S,H,dh); k,v: (B,S,KV,dh) (GQA)."""
     B, Sq, H, dh = q.shape
     qf, kf, vf = _flatten_gqa(q, k, v)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 I32 = jnp.int32
 
@@ -47,15 +49,21 @@ def _rowq(x, qmax):
     return q, scale
 
 
+def _step_input(x_ref, t):
+    """Step ``t`` of the (bn, T, K) window block as (bn, K) f32: a ref
+    slice, which Mosaic lowers (a ``dynamic_slice`` of a loaded value it
+    does not)."""
+    return x_ref[:, pl.ds(t, 1), :][:, 0].astype(F32)
+
+
 def _lstm_kernel(x_ref, wx_ref, wh_ref, b_ref, o_ref, *, t_steps, hidden):
-    x = x_ref[...].astype(F32)  # (bn, T, K)
     wx, wh, bias = wx_ref[...], wh_ref[...], b_ref[...]
-    bn = x.shape[0]
+    bn = x_ref.shape[0]
     h0 = jnp.zeros((bn, hidden), F32)
 
     def step(t, carry):
         h, c = carry
-        x_t = jax.lax.dynamic_slice_in_dim(x, t, 1, axis=1)[:, 0]
+        x_t = _step_input(x_ref, t)
         z = (jnp.dot(x_t, wx, preferred_element_type=F32)
              + jnp.dot(h, wh, preferred_element_type=F32) + bias)
         return _gates(z, c)
@@ -66,11 +74,10 @@ def _lstm_kernel(x_ref, wx_ref, wh_ref, b_ref, o_ref, *, t_steps, hidden):
 
 def _lstm_q_kernel(x_ref, wxq_ref, wxs_ref, whq_ref, whs_ref, b_ref, o_ref,
                    *, t_steps, hidden, qmax):
-    x = x_ref[...].astype(F32)
     wxq, whq = wxq_ref[...], whq_ref[...]  # (4H, K) / (4H, H) int8
     wxs, whs = wxs_ref[...].T, whs_ref[...].T  # (1, 4H) per-column scales
     bias = b_ref[...]
-    bn = x.shape[0]
+    bn = x_ref.shape[0]
     h0 = jnp.zeros((bn, hidden), F32)
 
     def qdot(a, wq, ws):
@@ -81,7 +88,7 @@ def _lstm_q_kernel(x_ref, wxq_ref, wxs_ref, whq_ref, whs_ref, b_ref, o_ref,
 
     def step(t, carry):
         h, c = carry
-        x_t = jax.lax.dynamic_slice_in_dim(x, t, 1, axis=1)[:, 0]
+        x_t = _step_input(x_ref, t)
         z = qdot(x_t, wxq, wxs) + qdot(h, whq, whs) + bias
         return _gates(z, c)
 
@@ -90,7 +97,7 @@ def _lstm_q_kernel(x_ref, wxq_ref, wxs_ref, whq_ref, whs_ref, b_ref, o_ref,
 
 
 def lstm_scan(kpms, wx, wh, b, *, block_rows: int = 128,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """kpms (B, T, K), wx (K, 4H), wh (H, 4H), b (4H,) -> final h (B, H)."""
     n, t_steps, k = kpms.shape
     hidden = wh.shape[0]
@@ -111,14 +118,14 @@ def lstm_scan(kpms, wx, wh, b, *, block_rows: int = 128,
         ],
         out_specs=pl.BlockSpec((bn, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, hidden), F32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kpms.astype(F32), jnp.asarray(wx, F32), jnp.asarray(wh, F32),
       jnp.asarray(b, F32).reshape(1, -1))
     return out[:n]
 
 
 def lstm_scan_q(kpms, wxq, wxs, whq, whs, b, *, qmax: int = 127,
-                block_rows: int = 128, interpret: bool = True):
+                block_rows: int = 128, interpret: bool | None = None):
     """int8-serving LSTM scan -> final h (B, H) in f32.
 
     ``wxq`` (4H, K) / ``whq`` (4H, H): int8 weights quantized rowwise per
@@ -147,7 +154,7 @@ def lstm_scan_q(kpms, wxq, wxs, whq, whs, b, *, qmax: int = 127,
         ],
         out_specs=pl.BlockSpec((bn, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, hidden), F32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kpms.astype(F32), wxq, jnp.asarray(wxs, F32), whq,
       jnp.asarray(whs, F32), jnp.asarray(b, F32).reshape(1, -1))
     return out[:n]

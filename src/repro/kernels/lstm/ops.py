@@ -11,7 +11,7 @@ from repro.kernels.lstm.ref import lstm_scan_q_ref, lstm_scan_ref
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def lstm_hidden(kpms, wx, wh, b, *, use_kernel: bool = True,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """(B, T, K) windows -> (B, H) final hidden state, fp32.
 
     The estimator's temporal branch minus its output projection:
@@ -23,7 +23,7 @@ def lstm_hidden(kpms, wx, wh, b, *, use_kernel: bool = True,
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def lstm_hidden_q(kpms, wxq, wxs, whq, whs, b, *, use_kernel: bool = True,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """int8-serving variant: pre-quantized weights (``quantize_rows(w.T)``
     layout), per-step dynamic activation quantization."""
     if use_kernel:

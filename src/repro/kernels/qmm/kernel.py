@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 I32 = jnp.int32
 
@@ -43,7 +45,7 @@ def _qmm_kernel(xq_ref, sx_ref, wq_ref, sw_ref, o_ref, acc_ref, *, nk):
 
 
 def qmm(xq, sx, wq, sw, *, block_m: int = 128, block_n: int = 128,
-        block_k: int = 512, interpret: bool = True):
+        block_k: int = 512, interpret: bool | None = None):
     """(M, K) int8 @ (N, K) int8 -> (M, N) f32.
 
     ``xq``/``sx``: rowwise-quantized activations + (M, 1) scales;
@@ -52,7 +54,10 @@ def qmm(xq, sx, wq, sw, *, block_m: int = 128, block_n: int = 128,
     arbitrary shapes cost nothing but the pad copy."""
     m, kdim = xq.shape
     n = wq.shape[0]
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, kdim)
+    # at least 8 output channels per tile: Mosaic lowers a 1-row weight
+    # tile as a matrix-vector product, which has no int8 -> int32 form
+    # (the estimator head's last layer has one output)
+    bm, bn, bk = min(block_m, m), min(block_n, max(n, 8)), min(block_k, kdim)
     pm, pn, pk = (-m) % bm, (-n) % bn, (-kdim) % bk
     if pm or pk:
         xq = jnp.pad(xq, ((0, pm), (0, pk)))
@@ -74,6 +79,6 @@ def qmm(xq, sx, wq, sw, *, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, npad), F32),
         scratch_shapes=[pltpu.VMEM((bm, bn), I32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xq, jnp.asarray(sx, F32), wq, jnp.asarray(sw, F32))
     return out[:m, :n]

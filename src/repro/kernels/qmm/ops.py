@@ -17,7 +17,8 @@ from repro.kernels.quant.ops import quantize_rows
 from repro.kernels.quant.ref import quantize_ref
 
 
-def quantize_weight(w, *, use_kernel: bool = True, interpret: bool = True):
+def quantize_weight(w, *, use_kernel: bool = True,
+                    interpret: bool | None = None):
     """(K, N) f32 weight -> ((N, K) int8, (N, 1) f32 scales).
 
     Rowwise quantization of ``w.T`` — one int8 row (and one scale) per
@@ -28,7 +29,7 @@ def quantize_weight(w, *, use_kernel: bool = True, interpret: bool = True):
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def int8_matmul(x, wq, sw, *, use_kernel: bool = True,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """x (M, K) f32 @ quantized weight -> (M, N) f32.
 
     Activations are quantized rowwise on the fly (exactly the

@@ -11,7 +11,7 @@ from repro.kernels.quant.ref import dequantize_ref, quantize_ref
 
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def quantize_rows(x, *, use_kernel=True, interpret=True):
+def quantize_rows(x, *, use_kernel=True, interpret=None):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     if use_kernel:

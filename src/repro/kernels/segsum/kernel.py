@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 I32 = jnp.int32
 
@@ -56,7 +58,7 @@ def _segreduce_kernel(v_ref, g_ref, o_ref, acc_ref, *, n_segments, nk, op):
 
 def segment_reduce_batched(values, seg_ids, n_segments: int, *,
                            op: str = "sum", block_t: int = 8,
-                           block_n: int = 512, interpret: bool = True):
+                           block_n: int = 512, interpret: bool | None = None):
     """values (T, N) f32 + seg_ids (T, N) i32 -> (T, C) reductions.
 
     ``op``: "sum" or "max". Segment ids outside ``[0, n_segments)`` are
@@ -85,6 +87,6 @@ def segment_reduce_batched(values, seg_ids, n_segments: int, *,
         out_specs=pl.BlockSpec((bt, n_segments), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((tp, n_segments), F32),
         scratch_shapes=[pltpu.VMEM((bt, n_segments), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(values.astype(F32), seg_ids.astype(I32))
     return out[:t]

@@ -21,7 +21,7 @@ from repro.kernels.segsum.ref import segment_reduce_ref
          static_argnames=("n_segments", "op", "use_kernel", "interpret"))
 def segment_reduce(values, seg_ids, n_segments: int, *, op: str = "sum",
                    mask=None, use_kernel: bool = True,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Reduce ``values`` into ``n_segments`` buckets keyed by ``seg_ids``.
 
     Accepts (N,) or (T, N) inputs (``seg_ids`` broadcasts against

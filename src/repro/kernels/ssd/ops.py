@@ -11,7 +11,7 @@ from repro.kernels.ssd.ref import ssd_ref, ssd_step_ref
 
 @partial(jax.jit, static_argnames=("chunk", "use_kernel", "interpret"))
 def ssd_mixer(x, dt, A, Bm, Cm, *, chunk=256, use_kernel=True,
-              interpret=True):
+              interpret=None):
     if use_kernel:
         return ssd(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
     return ssd_ref(x, dt, A, Bm, Cm, chunk)

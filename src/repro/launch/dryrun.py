@@ -3,6 +3,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 # ruff: noqa: E402  (XLA_FLAGS must precede every other import, incl. repro.*)
+import jax
+
+# An AOT tool on virtual host devices: it never opens an accelerator, so a
+# chip on this host stays with the process that serves on it. The --all
+# children run this module too and make the same choice.
+jax.config.update("jax_platforms", "cpu")
+
 import argparse
 import json
 import pathlib
@@ -19,13 +26,11 @@ def run_cell(arch: str, shape: str, multi_pod: bool, remat: str,
              overrides: dict | None, grad_accum: int | None,
              calibrate: bool = True, kv_dtype: str = "bf16",
              bf16_gather: bool = False, weight_dtype: str = "bf16") -> dict:
-    import jax  # noqa: F401  (after XLA_FLAGS)
-
     from repro.configs import SHAPES, cell_status, get_config
     from repro.dist import sharding as sh
     from repro.launch import roofline as rl
     from repro.launch.mesh import make_production_mesh
-    from repro.launch.steps import build_cell, compile_lowered, lower_cell
+    from repro.launch.steps import COMPILER_OPTS, build_cell, lower_cell
 
     import dataclasses as _dc
 
@@ -52,14 +57,13 @@ def run_cell(arch: str, shape: str, multi_pod: bool, remat: str,
         lowered = lower_cell(cell, mesh, overrides)
         rec["lower_s"] = round(time.time() - t0, 2)
         t1 = time.time()
-        compiled = compile_lowered(lowered)
+        compiled = lowered.compile(COMPILER_OPTS)
         rec["compile_s"] = round(time.time() - t1, 2)
         rec["grad_accum"] = cell.grad_accum
         mem = compiled.memory_analysis()
         rec["memory_analysis"] = str(mem)
-        from repro.launch.steps import cost_analysis_dict
         rec["cost_analysis"] = {
-            k: v for k, v in cost_analysis_dict(compiled).items()
+            k: v for k, v in (compiled.cost_analysis() or {}).items()
             if k in ("flops", "bytes accessed", "transcendentals")
         }
     costvec = None

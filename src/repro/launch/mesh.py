@@ -3,6 +3,14 @@ module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo shards through
+    ``with_sharding_constraint`` / GSPMD-style propagation, which rejects
+    the ``Explicit`` axes ``make_mesh`` defaults to on current JAX."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, ep: bool = False):
@@ -21,7 +29,7 @@ def make_production_mesh(*, multi_pod: bool = False, ep: bool = False):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, expert: int = 1):
@@ -45,6 +53,5 @@ def make_host_mesh(data: int = 1, model: int = 1, expert: int = 1):
     while (n // (data * expert)) % model:
         model -= 1
     if expert > 1:
-        return jax.make_mesh((data, expert, model),
-                             ("data", "expert", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((data, expert, model), ("data", "expert", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

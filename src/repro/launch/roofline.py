@@ -121,8 +121,7 @@ def collective_stats(hlo_text: str, n_devices: int):
 
 # ------------------------------------------------------------------ calibration
 def _costvec(compiled, n_dev) -> dict:
-    from repro.launch.steps import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     vec = {"flops": float(ca.get("flops", 0.0)),
            "bytes": float(ca.get("bytes accessed", 0.0))}
     stats = collective_stats(compiled.as_text(), n_dev)
@@ -169,8 +168,8 @@ def calibrated_costs(cfg, shape_name: str, mesh, overrides, *, remat="full",
                     cfg, shape_name, rs, n_layers=n_layers, variant=variant,
                     remat=remat, bf16_gather=bf16_gather,
                     micro_rows=gb // grad_accum if kind == "train" else None)
-                compiled = steps.compile_lowered(
-                    steps.lower_cell(cell, mesh, overrides))
+                compiled = steps.lower_cell(cell, mesh, overrides).compile(
+                    steps.COMPILER_OPTS)
                 return _costvec(compiled, n_dev)
 
             if kind == "train":
@@ -226,8 +225,7 @@ def roofline(compiled, mesh, cfg, shape_name: str, shapes: dict,
         for k in stats:
             stats[k].count = int(costvec.get("count:" + k, 0))
     else:
-        from repro.launch.steps import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         flops_dev = float(ca.get("flops", 0.0))
         bytes_dev = float(ca.get("bytes accessed", 0.0))
         stats = collective_stats(compiled.as_text(), n_dev)

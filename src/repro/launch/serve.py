@@ -34,6 +34,11 @@ import argparse
 import json
 
 import jax
+
+# AOT lowering on virtual host devices: never open an accelerator, so a chip
+# on this host stays with the process that serves on it.
+jax.config.update("jax_platforms", "cpu")
+
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -42,7 +47,7 @@ from repro.core import boundary
 from repro.core.splitting import lm_head, lm_split_points, lm_tail
 from repro.dist import sharding as sh
 from repro.launch.mesh import make_production_mesh
-from repro.launch.steps import (compile_lowered, serve_overrides,
+from repro.launch.steps import (COMPILER_OPTS, serve_overrides,
                                 serve_param_template)
 from repro.models import abstract_params
 from repro.models.template import shardings_from_template
@@ -66,7 +71,7 @@ def fleet_estimator_dryrun(n_ues: int, ep: bool) -> None:
     kpms = jax.ShapeDtypeStruct((n_ues, e.window, e.n_kpms), jnp.float32)
     iq = jax.ShapeDtypeStruct((n_ues, 2, e.n_sc, e.n_sym), jnp.float32)
     alloc = jax.ShapeDtypeStruct((n_ues,), jnp.float32)
-    compiled = compile_lowered(fn.lower(pabs, kpms, iq, alloc))
+    compiled = fn.lower(pabs, kpms, iq, alloc).compile(COMPILER_OPTS)
     # resolve the batch sharding the program actually gets: a fleet size
     # not divisible by the data axes falls back to replicated (Ruleset
     # rule 2), and the report must say so rather than claim shards
@@ -121,7 +126,7 @@ def fleet_online_dryrun(n_rows: int, batch: int, ep: bool) -> None:
     idx = jax.ShapeDtypeStruct((batch,), jnp.int32)
     key_abs = jax.eval_shape(jax.random.PRNGKey, 0)
     lowered = step.lower(pabs, opt_abs, data, idx, key_abs)
-    compiled = compile_lowered(lowered)
+    compiled = lowered.compile(COMPILER_OPTS)
     # the gradient psum is inserted by SPMD partitioning, so it only shows
     # in the compiled (post-partitioning) HLO, not the lowering
     try:
@@ -202,7 +207,7 @@ def main():
         head = jax.jit(lambda p, b: lm_head(cfg, p, b, k),
                        in_shardings=(psh, None))
         lowered = head.lower(pabs, batch_abs)
-        compiled = compile_lowered(lowered)
+        compiled = lowered.compile(COMPILER_OPTS)
         results["head_memory"] = str(compiled.memory_analysis())
         act_abs = jax.eval_shape(lambda p, b: lm_head(cfg, p, b, k),
                                  pabs, batch_abs)
@@ -211,7 +216,8 @@ def main():
         psh = shardings_from_template(tmpl, rs)
         tail = jax.jit(lambda p, a, b: lm_tail(cfg, p, a, b, k),
                        in_shardings=(psh, None, None))
-        compiled = compile_lowered(tail.lower(pabs, act_abs, batch_abs))
+        compiled = tail.lower(pabs, act_abs, batch_abs).compile(
+            COMPILER_OPTS)
         results["tail_memory"] = str(compiled.memory_analysis())
     ici_bw = 50e9
     results["boundary_transfer_ms"] = round(

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from typing import Optional
 
 import jax
@@ -362,37 +361,3 @@ def lower_cell(cell: Cell, mesh, overrides: Optional[dict] = None):
 # On real TPU deployments the same is controlled via collective-pipeliner
 # tuning; for the AOT dry-run this keeps the memory model deployment-faithful.
 COMPILER_OPTS = {"xla_disable_hlo_passes": "while-loop-invariant-code-motion"}
-
-_compiler_opts_ok = True
-
-
-def compile_lowered(lowered):
-    """Compile with COMPILER_OPTS, degrading to defaults on jaxlib builds
-    that cannot set repeated DebugOptions fields through compile options
-    (proto reflection rejects the string form). The dry-run memory model
-    is slightly less deployment-faithful without the LICM pin; tests and
-    serving correctness are unaffected."""
-    global _compiler_opts_ok
-    if _compiler_opts_ok:
-        try:
-            return lowered.compile(COMPILER_OPTS)
-        except RuntimeError as e:
-            if "xla_disable_hlo_passes" not in str(e):
-                raise
-            warnings.warn("this jaxlib cannot apply COMPILER_OPTS "
-                          "(repeated DebugOptions field); compiling with "
-                          "default passes", RuntimeWarning, stacklevel=2)
-            _compiler_opts_ok = False
-    return lowered.compile()
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
-
-    Depending on the jax build the method returns a dict or a one-element
-    list of dicts (one per program); every dry-run consumer wants the
-    dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca

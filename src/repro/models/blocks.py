@@ -28,10 +28,10 @@ MOE_GROUP = 1024  # tokens per MoE dispatch group
 INNER_UNROLL = False
 
 # Route full-sequence self-attention through the Pallas flash-attention
-# kernel (repro/kernels/flash_attention). interpret=True on CPU; on real
-# TPU this is the production path that keeps score tiles in VMEM.
+# kernel (repro/kernels/flash_attention). The kernel interprets on the cpu
+# backend and compiles on tpu (``kernels.resolve_interpret``); on the chip
+# this is the path that keeps score tiles in VMEM.
 USE_PALLAS_ATTENTION = False
-PALLAS_INTERPRET = True
 
 
 def _maybe_unrolled_scan(step, init, xs, length):
@@ -285,8 +285,7 @@ def attention_block(cfg, p, x, *, kind, window, positions, cross_kv=None,
     elif USE_PALLAS_ATTENTION and kind != "cross":
         from repro.kernels.flash_attention import mha  # lazy: optional path
         o = mha(q, k, v, causal=cfg.causal, window=window,
-                block_q=min(128, S), block_k=min(128, S),
-                interpret=PALLAS_INTERPRET)
+                block_q=min(128, S), block_k=min(128, S))
     elif window and S > window:
         o = local_attention(q, k, v, window=window, scale=scale)
     elif S > ATTN_CHUNK:
@@ -648,8 +647,7 @@ def ssd_block(cfg, p, x, dtype=DEFAULT_COMPUTE):
         from repro.kernels.ssd import ssd as ssd_kernel
         B_, S_ = x.shape[0], x.shape[1]
         yk, s_last = ssd_kernel(xh, dt, A, Bg, Cg,
-                                chunk=min(cfg.ssm_chunk, S_),
-                                interpret=PALLAS_INTERPRET)
+                                chunk=min(cfg.ssm_chunk, S_))
         nh = cfg.d_inner // cfg.ssm_headdim
         y = cast(yk, F32) + cast(p["Dskip"], F32)[:, None] * cast(xh, F32)
         y = y.reshape(B_, S_, cfg.d_inner)

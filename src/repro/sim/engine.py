@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -333,10 +334,20 @@ def emit_period_samples(episode: EpisodeBatch, t: int,
             "tp": episode.tp_mbps[:, t].astype(np.float32)}
 
 
-# Rows per fused estimator forward on the unsharded path: bounds the f32
-# activation working set (8192 rows of the default (2, 64, 14) IQ is
-# ~56 MB) while amortizing dispatch over many report periods per call.
-EST_CHUNK_ROWS = 8192
+# Device bytes one forward of the windowed estimator may hold on the
+# unsharded path. Rows per forward follow from it (``est_chunk_rows``), so a
+# chunk fits one chip's HBM at any IQ width while amortizing dispatch over
+# many report periods per call.
+EST_CHUNK_BYTES = 1 << 30
+# UEs per recurrent-estimator sequence pass (one row = one UE's stream)
+SSM_CHUNK_UES = 8192
+
+
+def est_chunk_rows(ecfg) -> int:
+    """Rows per estimator forward: as many ``ecfg.row_bytes`` rows as fit
+    ``EST_CHUNK_BYTES`` (about 225 at the paper's n_sc=3276, 11k at
+    n_sc=64)."""
+    return max(1, EST_CHUNK_BYTES // ecfg.row_bytes)
 
 
 def estimate_fleet(episode: EpisodeBatch, estimator, tp_clip=TP_CLIP_MBPS,
@@ -350,11 +361,11 @@ def estimate_fleet(episode: EpisodeBatch, estimator, tp_clip=TP_CLIP_MBPS,
     its (2, n_sc, 14) IQ spectrogram, and the fused prediction is clipped
     into the PSO sweep range (Mbps, default ``TP_CLIP_MBPS``). The
     unsharded path vectorizes *across report periods* too: as many whole
-    periods as fit in ``EST_CHUNK_ROWS`` rows are flattened into one
-    jitted forward (periods x fleet rows), so a T-period episode costs
-    ``ceil(N * T / EST_CHUNK_ROWS)`` dispatches instead of T — the numbers
-    are identical to the old per-period loop because the forward is
-    row-wise (pinned by ``tests/test_sim_fleet.py``).
+    periods of a block of UEs as fit in ``est_chunk_rows(ecfg)`` rows are
+    flattened into one jitted forward (periods x UE rows), so a T-period
+    episode costs about ``N * T / est_chunk_rows`` dispatches instead of
+    T — the numbers are identical to the old per-period loop because the
+    forward is row-wise (pinned by ``tests/test_sim_fleet.py``).
 
     ``estimator``: an ``(EstimatorConfig, params)`` pair, or an
     ``(SSMConfig, params)`` pair — the recurrent estimator
@@ -413,32 +424,39 @@ def estimate_fleet(episode: EpisodeBatch, estimator, tp_clip=TP_CLIP_MBPS,
         with telmod.stage("featurize"):
             wins = episode.kpm_windows(normalize=True).astype(np.float32)
     est = np.empty((n, t_steps))
-    periods = max(1, min(t_steps, EST_CHUNK_ROWS // max(n, 1)))
-    for t0 in range(0, t_steps, periods):
+    max_rows = est_chunk_rows(ecfg)
+    n_blk = max(1, min(n, max_rows))  # UEs per chunk: < n only at wide IQ
+    periods = max(1, min(t_steps, max_rows // n_blk))
+    for u0, t0 in itertools.product(range(0, n, n_blk),
+                                    range(0, t_steps, periods)):
+        us = slice(u0, min(u0 + n_blk, n))
+        m = us.stop - u0
         b = min(periods, t_steps - t0)
         sl = slice(t0, t0 + b)
-        rows = n * b
-        # (N, b, ...) -> (N*b, ...): row (u * b + j) is UE u at period t0+j
+        rows = m * b
+        # (m, b, ...) -> (m*b, ...): row (u * b + j) is UE u0+u at period
+        # t0+j
         if fused:
             with telmod.stage("featurize"):
                 # window j of the chunk covers trace steps
                 # [t0+j, t0+j+WINDOW)
-                kw = kpm_feature_windows(kpms_d[:, t0:t0 + b + WINDOW - 1],
-                                         center, scale, WINDOW)
+                kw = kpm_feature_windows(
+                    kpms_d[us, t0:t0 + b + WINDOW - 1], center, scale,
+                    WINDOW)
                 kpms_rows = kw.reshape(rows, WINDOW, kw.shape[-1])
         else:
-            kpms_rows = jnp.asarray(np.ascontiguousarray(wins[:, sl]).reshape(
-                rows, *wins.shape[2:]))
-        iq_rows = jnp.asarray(np.asarray(episode.iq[:, sl],
+            kpms_rows = jnp.asarray(np.ascontiguousarray(
+                wins[us, sl]).reshape(rows, *wins.shape[2:]))
+        iq_rows = jnp.asarray(np.asarray(episode.iq[us, sl],
                                          np.float32).reshape(
             rows, *episode.iq.shape[2:]))
-        alloc_rows = jnp.asarray(np.repeat(alloc, b))
+        alloc_rows = jnp.asarray(np.repeat(alloc[us], b))
         with telmod.stage("estimator_fwd"):
             if quant == "int8":
                 out = fwd_int8(ecfg, qparams, kpms_rows, iq_rows, alloc_rows)
             else:
                 out = fwd(ecfg, params, kpms_rows, iq_rows, alloc_rows)
-        est[:, sl] = np.asarray(out).reshape(n, b)
+        est[us, sl] = np.asarray(out).reshape(m, b)
     return np.clip(est, tp_clip[0], tp_clip[1])
 
 
@@ -447,7 +465,7 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
                         quant: Optional[str] = None) -> np.ndarray:
     """The recurrent arm of :func:`estimate_fleet`: the whole (N, T +
     WINDOW) report stream runs through one chunked SSD sequence pass per
-    ``EST_CHUNK_ROWS`` UEs (the first WINDOW - 1 reports warm the state,
+    ``SSM_CHUNK_UES`` UEs (the first WINDOW - 1 reports warm the state,
     matching the windowed path's label alignment), and the (K+1)
     forecasts collapse to the policy estimate. Under a ``serving`` mesh
     the same math runs as the per-period O(1) step program, state
@@ -470,11 +488,11 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
         return sharded_ssm_estimate(ecfg, params, feats, serving, tp_clip,
                                     n_periods=t_steps)
     est = np.empty((n, t_steps))
-    for i in range(0, n, EST_CHUNK_ROWS):
+    for i in range(0, n, SSM_CHUNK_UES):
         with telmod.stage("estimator_fwd"):
             fc, _ = ssm_forward_seq(ecfg, params,
-                                    jnp.asarray(feats[i:i + EST_CHUNK_ROWS]))
-        est[i:i + EST_CHUNK_ROWS] = reduce_forecasts(
+                                    jnp.asarray(feats[i:i + SSM_CHUNK_UES]))
+        est[i:i + SSM_CHUNK_UES] = reduce_forecasts(
             ecfg, np.asarray(fc[:, WINDOW - 1:WINDOW - 1 + t_steps]))
     return np.clip(est, tp_clip[0], tp_clip[1])
 

@@ -77,6 +77,29 @@ def _full_pool_schedule(n, T):
 
 
 # ---------------------------------------------------------- core module
+@pytest.mark.parametrize("mode", ["sequence", "step"])
+def test_contractions_trace_at_fp32(mode):
+    """Every contraction of both modes (projections, mixer, readout,
+    forecast rollout) is traced at ``highest`` precision whatever the
+    process default, so a backend whose default rounds f32 operands to
+    bf16 computes the same estimate as the fp32 ssd kernel."""
+    import re
+    c, p = tiny_ssm(forecast_horizon=2)
+    f = jnp.zeros((2, 8, c.n_feats), jnp.float32)
+    with jax.default_matmul_precision("bfloat16"):
+        if mode == "sequence":
+            jaxpr = jax.make_jaxpr(
+                lambda p, f: ssm_forward_seq(c, p, f))(p, f)
+        else:
+            jaxpr = jax.make_jaxpr(lambda p, s, f: ssm_step(c, p, s, f))(
+                p, ssm_state_init(c, (2,)), f[:, 0])
+    text = str(jaxpr)
+    # dot_general is the only primitive here that carries a precision
+    precisions = re.findall(r"precision=(\S+)", text)
+    assert len(precisions) == text.count("dot_general[") >= 5
+    assert set(precisions) == {"(Precision.HIGHEST,"}
+
+
 def test_config_validation_and_state_accounting():
     with pytest.raises(ValueError, match="n_heads"):
         SSMConfig(n_heads=3, n_groups=2)

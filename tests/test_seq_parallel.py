@@ -14,8 +14,8 @@ multi_device = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs >= 8 (virtual) devices")
 
 # mesh (data=2, model=4): the ("batch", "ctx", "embed") residual constraint
-# resolves to P("data", "model", None), whose HLO tiling is devices=[2,4,1]
-SEQ_SHARDED = "devices=[2,4,1]<=[8]"
+# resolves to P("data", "model", None), which lowers to this Shardy sharding
+SEQ_SHARDED = '[{"data"}, {"model"}, {}]>'
 
 
 def _lower_train4k(overrides):

@@ -91,8 +91,8 @@ def test_simulate_fleet_composes_with_serving():
 # ---------------------------------------------------------------- lowering
 @multi_device
 def test_lowering_shards_ue_batch_axis():
-    """The per-period program's HLO carries an 8-way tiling on dim 0 of the
-    batch inputs (mesh data=8): the UE batch axis is actually sharded."""
+    """The per-period program's lowering constrains dim 0 of the batch
+    inputs to the 8-way data axis: the UE batch axis is actually sharded."""
     e, params = tiny_estimator()
     serving = make_serving_mesh("8x1")
     assert dict(serving.mesh.shape) == {"data": 8, "model": 1}
@@ -105,10 +105,12 @@ def test_lowering_shards_ue_batch_axis():
         jax.ShapeDtypeStruct((n, 2, e.n_sc, e.n_sym), jnp.float32),
         jax.ShapeDtypeStruct((n,), jnp.float32))
     text = lowered.as_text()
-    # iq is rank 4, kpms rank 3; both must pick up dim-0 tiling over the
-    # 8-way data axis
-    assert "devices=[8,1,1,1]<=[8]" in text, "iq batch dim not sharded"
-    assert "devices=[8,1,1]<=[8]" in text, "kpms batch dim not sharded"
+    # iq is rank 4, kpms rank 3; both must carry a Shardy constraint that
+    # puts dim 0 on the 8-way data axis and replicates the rest
+    assert ('[{"data"}, {}, {}, {}]> : tensor<8x2x' in text), (
+        "iq batch dim not sharded")
+    assert ('[{"data"}, {}, {}]> : tensor<8x30x' in text), (
+        "kpms batch dim not sharded")
 
 
 @multi_device

@@ -74,6 +74,9 @@ def _check_pf_no_starvation(seed):
     rates = rng.uniform(20.0, 130.0, (25, n))
     rates[:, 0] = 1.0  # one persistently weak UE in cell 0
     cell_idx[0] = 0
+    # ...which it shares with at least one other UE: alone, its share is
+    # 1.0 every period and cannot grow (cells 1.. stay covered by 1..)
+    cell_idx[N_CELLS] = 0
     cfg = SchedulerConfig(policy="pf")
     shares, _ = _run_steps(cfg, cell_idx, rates)
     assert np.all(shares > 0.0)

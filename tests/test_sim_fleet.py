@@ -320,33 +320,86 @@ def test_estimate_fleet_shapes_and_clip():
     assert est.min() >= TP_CLIP_MBPS[0] and est.max() <= TP_CLIP_MBPS[1]
 
 
+def _per_period_reference(ep, e, params):
+    """The pre-vectorization loop: one estimator forward per period."""
+    from repro.estimator.train import predict
+    from repro.sim import TP_CLIP_MBPS
+    wins = ep.kpm_windows(normalize=True).astype(np.float32)
+    alloc = ep.alloc_ratio.astype(np.float32)
+    ref = np.empty((ep.n_ues, ep.n_steps))
+    for t in range(ep.n_steps):
+        data = {"kpms": wins[:, t], "iq": ep.iq[:, t].astype(np.float32),
+                "alloc": alloc, "tp": np.empty(ep.n_ues, np.float32)}
+        ref[:, t] = np.asarray(predict(e, params, data, batch=None))
+    return np.clip(ref, TP_CLIP_MBPS[0], TP_CLIP_MBPS[1])
+
+
+def _chunk_episode(n=3, T=7):
+    jax = pytest.importorskip("jax")
+    from repro.estimator.model import EstimatorConfig, init_estimator
+    rng = np.random.default_rng(9)
+    e = EstimatorConfig(n_sc=N_SC_TEST, lstm_hidden=8, hidden=8)
+    params = init_estimator(e, jax.random.PRNGKey(1))
+    scen = np.asarray(sc.SCENARIOS)[np.arange(n) % 4]
+    ep = sc.gen_episode_batch(scen, T, rng, n_sc=N_SC_TEST)
+    return e, params, ep
+
+
 def test_estimate_fleet_vectorized_matches_per_period_loop():
     """The period-chunked forward (many whole report periods flattened
     into one dispatch) must reproduce the old one-forward-per-period loop:
     the estimator is row-wise, so only the batch packing changed."""
-    jax = pytest.importorskip("jax")
-    from repro.estimator.model import EstimatorConfig, init_estimator
-    from repro.estimator.train import predict
-    from repro.sim import TP_CLIP_MBPS, estimate_fleet
-    from repro.sim.engine import EST_CHUNK_ROWS
-    rng = np.random.default_rng(9)
-    e = EstimatorConfig(n_sc=N_SC_TEST, lstm_hidden=8, hidden=8)
-    params = init_estimator(e, jax.random.PRNGKey(1))
-    n, T = 3, 7
-    scen = np.asarray(sc.SCENARIOS)[np.arange(n) % 4]
-    ep = sc.gen_episode_batch(scen, T, rng, n_sc=N_SC_TEST)
-    assert n * T <= EST_CHUNK_ROWS  # whole episode fits one chunk
+    from repro.sim import estimate_fleet
+    from repro.sim.engine import est_chunk_rows
+    e, params, ep = _chunk_episode()
+    assert ep.n_ues * ep.n_steps <= est_chunk_rows(e)  # one chunk
     est = estimate_fleet(ep, (e, params))
-    # reference: the pre-vectorization loop, one forward per period
-    wins = ep.kpm_windows(normalize=True).astype(np.float32)
-    alloc = ep.alloc_ratio.astype(np.float32)
-    ref = np.empty((n, T))
-    for t in range(T):
-        data = {"kpms": wins[:, t], "iq": ep.iq[:, t].astype(np.float32),
-                "alloc": alloc, "tp": np.empty(n, np.float32)}
-        ref[:, t] = np.asarray(predict(e, params, data, batch=None))
-    ref = np.clip(ref, TP_CLIP_MBPS[0], TP_CLIP_MBPS[1])
-    np.testing.assert_allclose(est, ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(est, _per_period_reference(ep, e, params),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("max_rows", [2, 7])
+def test_estimate_fleet_small_chunks_match_per_period_loop(monkeypatch,
+                                                           max_rows, fused):
+    """A byte budget smaller than the fleet splits the UE axis as well as
+    the period axis (2 rows: UE blocks of 2 + 1, one period each; 7 rows:
+    all 3 UEs, 2 periods, a 1-period tail). Every split reproduces the
+    per-period loop."""
+    from repro.sim import engine, estimate_fleet
+    e, params, ep = _chunk_episode()
+    monkeypatch.setattr(engine, "EST_CHUNK_BYTES",
+                        max_rows * e.row_bytes)
+    assert engine.est_chunk_rows(e) == max_rows
+    est = estimate_fleet(ep, (e, params), fused=fused)
+    np.testing.assert_allclose(est, _per_period_reference(ep, e, params),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_est_chunk_rows_fit_byte_budget_at_paper_width():
+    """At the paper's estimator width (n_sc=3276) one chunk's IQ input and
+    conv1 activations — shapes taken from the traced conv, not from the
+    row-bytes formula — stay under EST_CHUNK_BYTES (1 GiB), a sixteenth
+    of one v5e's 16 GB HBM; the old fixed 8192 rows needed ~27 GB."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.estimator.model import EstimatorConfig
+    from repro.sim.engine import EST_CHUNK_BYTES, est_chunk_rows
+    e = EstimatorConfig()
+    rows = est_chunk_rows(e)
+    assert EST_CHUNK_BYTES == 1 << 30
+    assert 100 <= rows < 8192
+    iq = jax.ShapeDtypeStruct((rows, e.n_sc, e.n_sym, 2), jnp.float32)
+    w1 = jax.ShapeDtypeStruct((3, 3, 2, e.cnn_ch[0]), jnp.float32)
+    conv1 = jax.eval_shape(lambda x, w: lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")),
+        iq, w1)
+    held = 4 * (np.prod(iq.shape) + np.prod(conv1.shape))
+    assert held <= EST_CHUNK_BYTES
+    assert 4 * 8192 * (np.prod(iq.shape[1:]) + np.prod(conv1.shape[1:])) \
+        > 16e9
 
 
 def test_split_metrics_zero_throughput_finite():
