@@ -25,7 +25,7 @@ from repro.sim.telemetry import (EVENT_NAMES, HostTelemetry, StageStat,
                                  TelemetryConfig, TelemetryEvent,
                                  TelemetryRecord, TelemetryState,
                                  telemetry_decode, telemetry_init,
-                                 telemetry_step, timed, timed_stages,
+                                 telemetry_step, timed,
                                  to_jsonl, to_prometheus, trace_capture)
 from repro.sim.telemetry import stage as telemetry_stage
 
@@ -51,4 +51,4 @@ __all__ = ["CellsResult", "DriftConfig", "DriftState", "EVENT_NAMES",
            "simulate_cells", "simulate_fleet", "simulate_fleet_looped",
            "simulate_pool", "split_metrics", "telemetry_decode",
            "telemetry_init", "telemetry_stage", "telemetry_step", "timed",
-           "timed_stages", "to_jsonl", "to_prometheus", "trace_capture"]
+           "to_jsonl", "to_prometheus", "trace_capture"]

@@ -244,9 +244,11 @@ def run_controllers(tables: np.ndarray, est_tp: np.ndarray,
     .tables``); ``warm_split``: scalar or (N,) deployed-split warm start."""
     sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
                       cfg.fallback_split)
-    return np.asarray(sweep(
-        jnp.asarray(tables, jnp.int32), jnp.asarray(warm_split, jnp.int32),
-        jnp.asarray(est_tp, jnp.float32)))
+    with telmod.stage("controller"):
+        with telmod.ingest() as put:
+            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
+                    put(est_tp, jnp.float32))
+        return np.asarray(sweep(*args))
 
 
 def run_scheduled(tables: np.ndarray, est_tp: np.ndarray,
@@ -267,11 +269,13 @@ def run_scheduled(tables: np.ndarray, est_tp: np.ndarray,
     """
     sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
                       cfg.fallback_split, sched, int(n_cells))
-    splits, shares = sweep(
-        jnp.asarray(tables, jnp.int32), jnp.asarray(warm_split, jnp.int32),
-        jnp.asarray(est_tp, jnp.float32), jnp.asarray(rate_mbps, jnp.float32),
-        jnp.asarray(cell_idx, jnp.int32))
-    return np.asarray(splits), np.asarray(shares)
+    with telmod.stage("controller"):
+        with telmod.ingest() as put:
+            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
+                    put(est_tp, jnp.float32), put(rate_mbps, jnp.float32),
+                    put(cell_idx, jnp.int32))
+        splits, shares = sweep(*args)
+        return np.asarray(splits), np.asarray(shares)
 
 
 def _run_controllers_telem(tables, est_tp, true_tp, cfg: ControllerConfig,
@@ -283,11 +287,12 @@ def _run_controllers_telem(tables, est_tp, true_tp, cfg: ControllerConfig,
     is a distinct compiled program."""
     sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
                       cfg.fallback_split, telem=tcfg)
-    splits, ts, rows = sweep(
-        jnp.asarray(tables, jnp.int32), jnp.asarray(warm_split, jnp.int32),
-        jnp.asarray(est_tp, jnp.float32), jnp.asarray(true_tp, jnp.float32),
-        dconst, dbytes, ts0)
-    return np.asarray(splits), ts, rows
+    with telmod.stage("controller"):
+        with telmod.ingest() as put:
+            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
+                    put(est_tp, jnp.float32), put(true_tp, jnp.float32))
+        splits, ts, rows = sweep(*args, dconst, dbytes, ts0)
+        return np.asarray(splits), ts, rows
 
 
 def _run_scheduled_telem(tables, est_tp, cfg: ControllerConfig, warm_split,
@@ -298,11 +303,13 @@ def _run_scheduled_telem(tables, est_tp, cfg: ControllerConfig, warm_split,
     carried through the scan."""
     sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
                       cfg.fallback_split, sched, int(n_cells), telem=tcfg)
-    splits, shares, ts, rows = sweep(
-        jnp.asarray(tables, jnp.int32), jnp.asarray(warm_split, jnp.int32),
-        jnp.asarray(est_tp, jnp.float32), jnp.asarray(rate_mbps, jnp.float32),
-        jnp.asarray(cell_idx, jnp.int32), dconst, dbytes, ts0)
-    return np.asarray(splits), np.asarray(shares), ts, rows
+    with telmod.stage("controller"):
+        with telmod.ingest() as put:
+            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
+                    put(est_tp, jnp.float32), put(rate_mbps, jnp.float32),
+                    put(cell_idx, jnp.int32))
+        splits, shares, ts, rows = sweep(*args, dconst, dbytes, ts0)
+        return np.asarray(splits), np.asarray(shares), ts, rows
 
 
 def emit_period_samples(episode: EpisodeBatch, t: int,
@@ -405,21 +412,22 @@ def estimate_fleet(episode: EpisodeBatch, estimator, tp_clip=TP_CLIP_MBPS,
     n, t_steps = episode.n_ues, episode.n_steps
     alloc = episode.alloc_ratio.astype(np.float32)
     if serving is not None:
-        if fused:
-            # normalized trace, windowed per period as a view (the f64
-            # normalize + f32 cast matches kpm_windows bit-for-bit)
-            trace = kpmmod.normalize_kpms(episode.kpms).astype(np.float32)
-            return sharded_fleet_estimate(ecfg, params, trace, episode.iq,
-                                          alloc, serving, tp_clip,
-                                          quant=quant, window=WINDOW)
-        wins = episode.kpm_windows(normalize=True).astype(np.float32)
-        return sharded_fleet_estimate(ecfg, params, wins, episode.iq,
-                                      alloc, serving, tp_clip, quant=quant)
+        with telmod.stage("featurize"):
+            if fused:
+                # normalized trace, windowed per period as a view (the f64
+                # normalize + f32 cast matches kpm_windows bit-for-bit)
+                wins = kpmmod.normalize_kpms(episode.kpms).astype(np.float32)
+            else:
+                wins = episode.kpm_windows(normalize=True).astype(np.float32)
+        return sharded_fleet_estimate(ecfg, params, wins, episode.iq, alloc,
+                                      serving, tp_clip, quant=quant,
+                                      window=WINDOW if fused else None)
     qparams = quantize_estimator(params) if quant == "int8" else None
     if fused:
-        kpms_d = jnp.asarray(episode.kpms, jnp.float32)
-        center = jnp.asarray(kpmmod.KPM_CENTER)
-        scale = jnp.asarray(kpmmod.KPM_SCALE)
+        with telmod.stage("featurize"), telmod.ingest() as put:
+            kpms_d = put(episode.kpms, jnp.float32)
+            center = put(kpmmod.KPM_CENTER)
+            scale = put(kpmmod.KPM_SCALE)
     else:
         with telmod.stage("featurize"):
             wins = episode.kpm_windows(normalize=True).astype(np.float32)
@@ -444,19 +452,20 @@ def estimate_fleet(episode: EpisodeBatch, estimator, tp_clip=TP_CLIP_MBPS,
                     kpms_d[us, t0:t0 + b + WINDOW - 1], center, scale,
                     WINDOW)
                 kpms_rows = kw.reshape(rows, WINDOW, kw.shape[-1])
-        else:
-            kpms_rows = jnp.asarray(np.ascontiguousarray(
-                wins[us, sl]).reshape(rows, *wins.shape[2:]))
-        iq_rows = jnp.asarray(np.asarray(episode.iq[us, sl],
-                                         np.float32).reshape(
-            rows, *episode.iq.shape[2:]))
-        alloc_rows = jnp.asarray(np.repeat(alloc[us], b))
         with telmod.stage("estimator_fwd"):
+            with telmod.ingest() as put:
+                if not fused:
+                    kpms_rows = put(np.ascontiguousarray(
+                        wins[us, sl]).reshape(rows, *wins.shape[2:]))
+                iq_rows = put(np.asarray(episode.iq[us, sl],
+                                         np.float32).reshape(
+                    rows, *episode.iq.shape[2:]))
+                alloc_rows = put(np.repeat(alloc[us], b))
             if quant == "int8":
                 out = fwd_int8(ecfg, qparams, kpms_rows, iq_rows, alloc_rows)
             else:
                 out = fwd(ecfg, params, kpms_rows, iq_rows, alloc_rows)
-        est[us, sl] = np.asarray(out).reshape(m, b)
+            est[us, sl] = np.asarray(out).reshape(m, b)
     return np.clip(est, tp_clip[0], tp_clip[1])
 
 
@@ -482,18 +491,21 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
                          "snapshots: generate the episode with "
                          "include_iq=True")
     n, t_steps = episode.n_ues, episode.n_steps
-    feats = episode_features(episode.kpms, episode.alloc_ratio,
-                             episode.iq if ecfg.include_iq else None)
+    with telmod.stage("featurize"):
+        feats = episode_features(episode.kpms, episode.alloc_ratio,
+                                 episode.iq if ecfg.include_iq else None)
     if serving is not None:
         return sharded_ssm_estimate(ecfg, params, feats, serving, tp_clip,
                                     n_periods=t_steps)
     est = np.empty((n, t_steps))
     for i in range(0, n, SSM_CHUNK_UES):
         with telmod.stage("estimator_fwd"):
-            fc, _ = ssm_forward_seq(ecfg, params,
-                                    jnp.asarray(feats[i:i + SSM_CHUNK_UES]))
-        est[i:i + SSM_CHUNK_UES] = reduce_forecasts(
-            ecfg, np.asarray(fc[:, WINDOW - 1:WINDOW - 1 + t_steps]))
+            with telmod.ingest() as put:
+                x = put(feats[i:i + SSM_CHUNK_UES])
+            fc, _ = ssm_forward_seq(ecfg, params, x)
+            del x  # freed with its pass, not held over the next copy
+            est[i:i + SSM_CHUNK_UES] = reduce_forecasts(
+                ecfg, np.asarray(fc[:, WINDOW - 1:WINDOW - 1 + t_steps]))
     return np.clip(est, tp_clip[0], tp_clip[1])
 
 
@@ -652,13 +664,15 @@ def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
                     cell_idx, true_tp, telemetry, dconst, dbytes, tel.ts)
             eff_tp = tpmod.prb_scaled_mbps(true_tp, shares)
             est_tp = est_tp * shares  # what the controllers consumed
-    delay, priv, energy = split_metrics(profile, splits, eff_tp, ue, server)
-    fixed = None
-    if fixed_split is not None:
-        fsplits = np.full_like(splits, fixed_split)
-        fd, fp, fe = split_metrics(profile, fsplits, eff_tp, ue, server)
-        fixed = FleetResult(fsplits, true_tp, est_tp, fd, fp, fe,
-                            prb_share=shares)
+    with telmod.stage("split_metrics"):
+        delay, priv, energy = split_metrics(profile, splits, eff_tp, ue,
+                                            server)
+        fixed = None
+        if fixed_split is not None:
+            fsplits = np.full_like(splits, fixed_split)
+            fd, fp, fe = split_metrics(profile, fsplits, eff_tp, ue, server)
+            fixed = FleetResult(fsplits, true_tp, est_tp, fd, fp, fe,
+                                prb_share=shares)
     return FleetResult(splits, true_tp, est_tp, delay, priv, energy, fixed,
                        prb_share=shares, online=online_stats,
                        telemetry=tel.decode(rows) if tel is not None

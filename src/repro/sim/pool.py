@@ -359,6 +359,16 @@ def _pool_tables(table, n_sessions: int) -> np.ndarray:
     return np.asarray(table.table)[None]  # shared row, broadcast on device
 
 
+def _pool_inputs(put, tables_np, warm_split, true_np, cell,
+                 schedule: ChurnSchedule) -> tuple:
+    """The per-session arrays both pool loops read, copied with ``put``:
+    (tables, warm split, true tp, cell, dwell, arrival period)."""
+    return (put(tables_np, I32), put(warm_split, I32), put(true_np, F32),
+            put(cell if cell is not None else
+                np.zeros(schedule.n_sessions), I32),
+            put(schedule.dwell, I32), put(schedule.arrival_t, I32))
+
+
 def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
                   profile: SplitProfile, cfg: ControllerConfig, *,
                   capacity: int, warm_split=None, estimator=None,
@@ -409,13 +419,6 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
     programs = pool_programs(cfg.ewma_alpha, cfg.hysteresis_steps,
                              cfg.fallback_split, sched, int(n_cells),
                              int(schedule.max_admits), telem=telemetry)
-    st0 = pool_init(capacity, warm_split)
-    tables_d = jnp.asarray(tables_np, I32)
-    warm_d = jnp.asarray(warm_split, I32)
-    true_d = jnp.asarray(true_np, F32)
-    cell_d = jnp.asarray(cell if cell is not None else np.zeros(m), I32)
-    dwell_d = jnp.asarray(schedule.dwell, I32)
-    arrival_d = jnp.asarray(schedule.arrival_t, I32)
     tel = dconst = dbytes = None
     if telemetry is not None:
         tel = telmod.HostTelemetry(telemetry)
@@ -426,10 +429,13 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
     online_stats = None
     telem_rec = None
     if online is not None:
+        st0 = pool_init(capacity, warm_split)
+        with telmod.ingest() as put:
+            inputs = _pool_inputs(put, tables_np, warm_split, true_np, cell,
+                                  schedule)
         outs, est_tp, online_stats = _online_pool_run(
-            sessions, schedule, estimator, online, programs, st0, tables_d,
-            warm_d, true_d, cell_d, dwell_d, arrival_d, serving=serving,
-            fused=fused, telemetry=tel, tel_dconst=dconst,
+            sessions, schedule, estimator, online, programs, st0, *inputs,
+            serving=serving, fused=fused, telemetry=tel, tel_dconst=dconst,
             tel_dbytes=dbytes, tel_sched=sched is not None)
         act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = outs
         if tel is not None:
@@ -438,37 +444,43 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
         est_np = (estimate_fleet(sessions, estimator, serving=serving,
                                  quant=quant, fused=fused)
                   if estimator is not None else true_np)
-        est_d = jnp.asarray(est_np, F32)
-        if telemetry is None:
-            _, ys = programs.sweep(st0, tables_d, warm_d, est_d, true_d,
-                                   cell_d, dwell_d, arrival_d,
-                                   jnp.asarray(schedule.ready_end, I32))
-        else:
-            (_, tel.ts), ys = programs.sweep(
-                st0, tel.ts, tables_d, warm_d, est_d, true_d, cell_d,
-                dwell_d, arrival_d, jnp.asarray(schedule.ready_end, I32),
-                dconst, dbytes)
-            ys, rows = ys[:7], ys[7]
-            telem_rec = tel.decode(rows)
-        act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = (
-            np.asarray(y) for y in ys)
+        with telmod.stage("controller"):
+            st0 = pool_init(capacity, warm_split)
+            with telmod.ingest() as put:
+                (tables_d, warm_d, true_d, cell_d, dwell_d,
+                 arrival_d) = _pool_inputs(put, tables_np, warm_split,
+                                           true_np, cell, schedule)
+                est_d = put(est_np, F32)
+                ready_d = put(schedule.ready_end, I32)
+            if telemetry is None:
+                _, ys = programs.sweep(st0, tables_d, warm_d, est_d, true_d,
+                                       cell_d, dwell_d, arrival_d, ready_d)
+            else:
+                (_, tel.ts), ys = programs.sweep(
+                    st0, tel.ts, tables_d, warm_d, est_d, true_d, cell_d,
+                    dwell_d, arrival_d, ready_d, dconst, dbytes)
+                ys, rows = ys[:7], ys[7]
+                telem_rec = tel.decode(rows)
+            act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = (
+                np.asarray(y) for y in ys)
         est_tp = None  # gathered below from the per-session estimates
 
-    act = act_ts.T  # (S, T)
-    sid = np.clip(sid_ts.T, 0, m - 1)
-    age = np.clip(age_ts.T, 0, sessions.n_steps - 1)
-    splits = split_ts.T.astype(np.int32)
-    true_tp = np.where(act, true_np[sid, age], 0.0)
-    if est_tp is None:
-        est_src = est_np if estimator is not None else true_np
-        est_tp = np.where(act, np.asarray(est_src, float)[sid, age], 0.0)
-    shares = None
-    if sched is not None:
-        shares = np.where(act, share_ts.T, 0.0)
-        eff_tp = tpmod.prb_scaled_mbps(true_tp, shares)
-        est_tp = est_tp * shares  # what the controllers consumed
-    else:
-        eff_tp = true_tp
+    with telmod.stage("gather"):
+        act = act_ts.T  # (S, T)
+        sid = np.clip(sid_ts.T, 0, m - 1)
+        age = np.clip(age_ts.T, 0, sessions.n_steps - 1)
+        splits = split_ts.T.astype(np.int32)
+        true_tp = np.where(act, true_np[sid, age], 0.0)
+        if est_tp is None:
+            est_src = est_np if estimator is not None else true_np
+            est_tp = np.where(act, np.asarray(est_src, float)[sid, age], 0.0)
+        shares = None
+        if sched is not None:
+            shares = np.where(act, share_ts.T, 0.0)
+            eff_tp = tpmod.prb_scaled_mbps(true_tp, shares)
+            est_tp = est_tp * shares  # what the controllers consumed
+        else:
+            eff_tp = true_tp
 
     def _metrics(l):
         d, p, e = split_metrics(profile, np.where(act, l, 0), eff_tp,
@@ -477,13 +489,14 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
         return (np.where(act, d, nan), np.where(act, p, nan),
                 np.where(act, e, nan))
 
-    delay, priv, energy = _metrics(splits)
-    fixed = None
-    if fixed_split is not None:
-        fsplits = np.where(act, fixed_split, NO_SPLIT).astype(np.int32)
-        fd, fp, fe = _metrics(fsplits)
-        fixed = FleetResult(fsplits, true_tp, est_tp, fd, fp, fe,
-                            prb_share=shares, active=act)
+    with telmod.stage("split_metrics"):
+        delay, priv, energy = _metrics(splits)
+        fixed = None
+        if fixed_split is not None:
+            fsplits = np.where(act, fixed_split, NO_SPLIT).astype(np.int32)
+            fd, fp, fe = _metrics(fsplits)
+            fixed = FleetResult(fsplits, true_tp, est_tp, fd, fp, fe,
+                                prb_share=shares, active=act)
     lat_valid = lat_ts >= 0
     stats = LifecycleStats(
         capacity=int(capacity), n_sessions=m,

@@ -52,6 +52,7 @@ from repro.estimator.serve import (check_quant, estimator_forward_int8,
 from repro.estimator.ssm import (SSMConfig, reduce_forecasts,
                                  ssm_state_init, ssm_step)
 from repro.launch.mesh import make_host_mesh
+from repro.sim import telemetry as telmod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +125,7 @@ def serving_program(ecfg: EstimatorConfig, serving: ServingMesh,
     mesh, overrides = serving.mesh, serving.rule_overrides()
 
     @jax.jit
-    def fn(params, kpms, iq, alloc):
+    def fwd_sharded(params, kpms, iq, alloc):
         with sh.use_rules(mesh, overrides), \
                 jax.named_scope("estimator_fwd"):
             if quant == "int8":
@@ -132,7 +133,7 @@ def serving_program(ecfg: EstimatorConfig, serving: ServingMesh,
                                               use_kernel=False)
             return estimator_forward(ecfg, params, kpms, iq, alloc)
 
-    return fn
+    return fwd_sharded
 
 
 def replicate_params(serving: ServingMesh, params):
@@ -177,16 +178,20 @@ def sharded_fleet_estimate(ecfg: EstimatorConfig, params, wins: np.ndarray,
         params = quantize_estimator(params, use_kernel=False)
     params_r = replicate_params(serving, params)
     with sh.use_rules(serving.mesh, serving.rule_overrides()):
-        alloc_d = sh.put(jnp.asarray(alloc, jnp.float32), ("batch",))
+        with telmod.stage("estimator_fwd"), telmod.ingest() as put:
+            alloc_d = sh.put(put(alloc, jnp.float32), ("batch",))
         est = np.empty((n, t_steps))
         for t in range(t_steps):
-            win_t = wins[:, t] if window is None else wins[:, t:t + window]
-            kpms_t = sh.put(jnp.asarray(win_t), ("batch", None, None))
-            iq_t = sh.put(jnp.asarray(iq[:, t], jnp.float32),
-                          ("batch", None, None, None))
-            est[:, t] = np.clip(np.asarray(fn(params_r, kpms_t, iq_t,
-                                              alloc_d)),
-                                tp_clip[0], tp_clip[1])
+            with telmod.stage("estimator_fwd"):
+                with telmod.ingest() as put:
+                    win_t = (wins[:, t] if window is None
+                             else wins[:, t:t + window])
+                    kpms_t = sh.put(put(win_t), ("batch", None, None))
+                    iq_t = sh.put(put(iq[:, t], jnp.float32),
+                                  ("batch", None, None, None))
+                est[:, t] = np.clip(np.asarray(fn(params_r, kpms_t, iq_t,
+                                                  alloc_d)),
+                                    tp_clip[0], tp_clip[1])
     return est
 
 
@@ -208,12 +213,12 @@ def ssm_serving_program(c: SSMConfig, serving: ServingMesh):
     mesh, overrides = serving.mesh, serving.rule_overrides()
 
     @jax.jit
-    def fn(params, state, feats):
+    def ssm_step_sharded(params, state, feats):
         with sh.use_rules(mesh, overrides), \
                 jax.named_scope("estimator_fwd"):
             return ssm_step(c, params, state, feats)
 
-    return fn
+    return ssm_step_sharded
 
 
 def sharded_ssm_estimate(c: SSMConfig, params, feats: np.ndarray,
@@ -239,11 +244,13 @@ def sharded_ssm_estimate(c: SSMConfig, params, feats: np.ndarray,
     with sh.use_rules(serving.mesh, serving.rule_overrides()):
         state = sh.put(ssm_state_init(c, (n,)), STATE_AXES)
         for col in range(off + n_periods):
-            feats_t = sh.put(jnp.asarray(feats[:, col], jnp.float32),
-                             ("batch", None))
-            state, fc = fn(params_r, state, feats_t)
-            if col >= off:
-                est[:, col - off] = np.clip(
-                    reduce_forecasts(c, np.asarray(fc)),
-                    tp_clip[0], tp_clip[1])
+            with telmod.stage("estimator_fwd"):
+                with telmod.ingest() as put:
+                    feats_t = sh.put(put(feats[:, col], jnp.float32),
+                                     ("batch", None))
+                state, fc = fn(params_r, state, feats_t)
+                if col >= off:
+                    est[:, col - off] = np.clip(
+                        reduce_forecasts(c, np.asarray(fc)),
+                        tp_clip[0], tp_clip[1])
     return est

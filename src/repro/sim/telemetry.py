@@ -23,10 +23,11 @@ fleet health a first-class, device-resident plane:
     rows -> a :class:`TelemetryRecord` of plain numpy/dataclass fields
     with JSON-lines and Prometheus-text exporters
     (:func:`to_jsonl`, :func:`to_prometheus`);
-  * :func:`stage` / :func:`timed_stages` / :func:`trace_capture` —
+  * :func:`stage` / :func:`ingest` / :func:`trace_capture` —
     ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` spans around
-    the report-period stages and the reusable best/median/spread timer
-    behind ``benchmarks/fleet.py --profile``.
+    the report-period stages, with event stats (the bytes each
+    host-to-device copy moved) riding on them; :func:`timed` is the
+    best/median/spread timer behind ``benchmarks/fleet.py --profile``.
 
 ``simulate_fleet(telemetry=None)`` (the default) never builds any of
 this — the traced programs are bit-identical to the prior engine,
@@ -44,7 +45,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import Callable, Mapping, NamedTuple, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -584,14 +585,42 @@ def to_prometheus(record: TelemetryRecord, prefix: str = "fleet") -> str:
 
 # ---------------------------------------------------------- stage tracing
 @contextlib.contextmanager
-def stage(name: str):
-    """A named report-period stage: ``jax.named_scope`` labels the traced
-    ops (visible in HLO / profiler op names) and
-    ``jax.profiler.TraceAnnotation`` spans the host wall time (visible on
-    the profiler timeline under a ``trace_capture``). Numerically a
-    no-op."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+def stage(name: str, **stats):
+    """A named report-period stage: ``jax.named_scope`` labels any ops
+    traced inside it and ``jax.profiler.TraceAnnotation`` spans its host
+    wall time on the profiler timeline, on the same clock as the device's
+    ops. ``stats`` (ints, floats, strings) ride on the span as event
+    stats; the span is yielded, so ``span.set_metadata(...)`` can add more
+    before it closes. With no profiler session open it costs a few
+    microseconds. Numerically a no-op."""
+    with jax.named_scope(name), \
+            jax.profiler.TraceAnnotation(name, **stats) as span:
+        yield span
+
+
+class _Put:
+    """``jnp.asarray`` that counts the bytes of what it made."""
+
+    def __init__(self):
+        self.nbytes = 0
+
+    def __call__(self, x, dtype=None):
+        out = jnp.asarray(x, dtype)
+        self.nbytes += out.nbytes
+        return out
+
+
+@contextlib.contextmanager
+def ingest():
+    """One stage's host-to-device copies as one ``ingest`` span. Yields
+    ``put`` (``jnp.asarray`` on host arrays); when the span closes, its
+    ``bytes`` stat is the ``nbytes`` of every array ``put`` made. Build
+    the host array inside the span too, so the cast and reshape that feed
+    a copy count as ingest."""
+    put = _Put()
+    with stage("ingest") as span:
+        yield put
+        span.set_metadata(bytes=put.nbytes)
 
 
 @contextlib.contextmanager
@@ -629,16 +658,3 @@ def timed(fn: Callable[[], object], reps: int = 2) -> StageStat:
     return StageStat(best=min(times), median=float(np.median(times)),
                      spread=max(times) - min(times))
 
-
-def timed_stages(stages: Mapping[str, Callable[[], object]],
-                 reps: int = 2) -> dict:
-    """name -> :class:`StageStat` for a dict of stage thunks, each run
-    under its :func:`stage` span (so a concurrent ``trace_capture`` sees
-    the same labels the wall-clock table reports)."""
-    out = {}
-    for name, fn in stages.items():
-        with stage(name):
-            fn()  # warm (and span the compile, if any, under the label)
-        with stage(name):
-            out[name] = timed(fn, reps)
-    return out
