@@ -40,8 +40,9 @@ from repro.core.profiles import SplitProfile
 # the estimator clamp range is part of the PSO sweep config, not ours
 from repro.core.pso import TP_CLIP_MBPS, LookupTable, StackedLookupTable
 from repro.estimator.serve import check_quant, fwd_int8, quantize_estimator
-from repro.estimator.ssm import (SSMConfig, episode_features,
-                                 reduce_forecasts, ssm_forward_seq)
+from repro.estimator.ssm import (SSMConfig, episode_features, iq_columns,
+                                 reduce_forecasts, report_alloc,
+                                 report_features, ssm_forward_seq)
 from repro.estimator.train import fwd
 from repro.kernels.featurize import kpm_feature_windows
 from repro.sim import telemetry as telmod
@@ -476,7 +477,9 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
     WINDOW) report stream runs through one chunked SSD sequence pass per
     ``SSM_CHUNK_UES`` UEs (the first WINDOW - 1 reports warm the state,
     matching the windowed path's label alignment), and the (K+1)
-    forecasts collapse to the policy estimate. Under a ``serving`` mesh
+    forecasts collapse to the policy estimate. Each chunk's raw reports
+    are copied in and made into features on the device
+    (``report_features``). Under a ``serving`` mesh
     the same math runs as the per-period O(1) step program, state
     sharded over the batch axis (pinned allclose by
     ``tests/test_estimator_ssm.py``)."""
@@ -491,20 +494,33 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
                          "snapshots: generate the episode with "
                          "include_iq=True")
     n, t_steps = episode.n_ues, episode.n_steps
-    with telmod.stage("featurize"):
-        feats = episode_features(episode.kpms, episode.alloc_ratio,
-                                 episode.iq if ecfg.include_iq else None)
+    iq = episode.iq if ecfg.include_iq else None
     if serving is not None:
+        with telmod.stage("featurize"):
+            feats = episode_features(episode.kpms, episode.alloc_ratio, iq)
         return sharded_ssm_estimate(ecfg, params, feats, serving, tp_clip,
                                     n_periods=t_steps)
+    s = episode.kpms.shape[1]
+    if iq is not None:
+        with telmod.stage("featurize"):
+            iq = iq_columns(iq, s)
     est = np.empty((n, t_steps))
     for i in range(0, n, SSM_CHUNK_UES):
-        with telmod.stage("estimator_fwd"):
+        rows = slice(i, min(i + SSM_CHUNK_UES, n))
+        # the chunk's raw reports go in flat; its features are built on
+        # the device and freed with its pass, not held over the next copy
+        with telmod.stage("featurize"):
             with telmod.ingest() as put:
-                x = put(feats[i:i + SSM_CHUNK_UES])
+                kpms_d = put(episode.kpms[rows].reshape(-1), jnp.float32)
+                alloc_d = put(report_alloc(episode.alloc_ratio[rows], s))
+                iq_d = None if iq is None else put(iq[rows])
+            x = report_features((rows.stop - i, s), kpms_d, alloc_d,
+                                kpmmod.KPM_CENTER, kpmmod.KPM_SCALE, iq_d)
+            del kpms_d, alloc_d, iq_d
+        with telmod.stage("estimator_fwd"):
             fc, _ = ssm_forward_seq(ecfg, params, x)
-            del x  # freed with its pass, not held over the next copy
-            est[i:i + SSM_CHUNK_UES] = reduce_forecasts(
+            del x
+            est[rows] = reduce_forecasts(
                 ecfg, np.asarray(fc[:, WINDOW - 1:WINDOW - 1 + t_steps]))
     return np.clip(est, tp_clip[0], tp_clip[1])
 

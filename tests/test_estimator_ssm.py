@@ -19,13 +19,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.channel import kpm as kpmmod
 from repro.channel import scenarios as sc
 from repro.channel.scenarios import WINDOW
 from repro.core.controller import ControllerConfig
 from repro.core.pso import LookupTable
 from repro.estimator.model import EstimatorConfig, init_estimator
 from repro.estimator.ssm import (N_IQ_FEATS, SSMConfig, episode_features,
-                                 init_ssm, iq_features, reduce_forecasts,
+                                 init_ssm, iq_columns, iq_features,
+                                 reduce_forecasts, report_alloc,
+                                 report_features, rounded_quotient,
                                  ssm_forward_seq, ssm_state_init, ssm_step,
                                  ssm_warm_state)
 from repro.estimator.train import fwd, ssm_predict, train_ssm
@@ -37,6 +40,7 @@ from repro.sim import (DriftConfig, OnlineConfig, SchedulerConfig, buffer_add,
                        make_serving_mesh, online_estimate_fleet,
                        online_step_program, run_controllers, run_scheduled,
                        simulate_fleet)
+from repro.sim import engine
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs >= 8 (virtual) devices")
@@ -165,6 +169,162 @@ def test_include_iq_estimate_and_missing_iq_guard():
         estimate_fleet(episode(4, T=6), (c, params))
     with pytest.raises(ValueError, match="include_iq"):
         online_estimate_fleet(episode(4, T=6), (c, params), OnlineConfig())
+
+
+def _host_features(kpms, alloc, iq=None):
+    """The report features in f32 NumPy: normalized KPMs, the clipped
+    alloc ratio broadcast as channel 15, then the IQ columns."""
+    k = (np.asarray(kpms, np.float32) - kpmmod.KPM_CENTER) / kpmmod.KPM_SCALE
+    a = np.clip(np.asarray(alloc, np.float32), 0.0, 1.0)
+    cols = [k, np.broadcast_to(a[:, None, None], k.shape[:2] + (1,))]
+    if iq is not None:
+        cols.append(iq_columns(iq, k.shape[1]))
+    return np.concatenate(cols, -1)
+
+
+@pytest.mark.parametrize("flat", [False, True])
+@pytest.mark.parametrize("n,s", [(1, WINDOW + 1), (7, 45), (300, 56),
+                                 (engine.SSM_CHUNK_UES // 64, 56)])
+def test_report_features_bit_equal_to_numpy(n, s, flat):
+    """The device features == the f32 NumPy normalisation bit for bit on
+    the CPU, from shaped or flat reports, alloc ratios below 0 and above 1
+    clipped alike, at shapes down to a chunk far shorter than
+    ``SSM_CHUNK_UES``."""
+    rng = np.random.default_rng(n + s)
+    kpms = (kpmmod.KPM_CENTER + kpmmod.KPM_SCALE
+            * rng.normal(size=(n, s, 15))).astype(np.float32)
+    alloc = rng.uniform(-0.5, 1.5, n)
+    alloc[:2] = (-0.25, 1.25)[:n]
+    per_report = report_alloc(alloc, s)
+    assert per_report.shape == (n * s,) and per_report.dtype == np.float32
+    x = kpms.reshape(-1) if flat else kpms
+    a = per_report if flat else per_report.reshape(n, s)
+    got = report_features((n, s), jnp.asarray(x), jnp.asarray(a),
+                          kpmmod.KPM_CENTER, kpmmod.KPM_SCALE)
+    want = _host_features(kpms, alloc)
+    assert got.shape == (n, s, 16) and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(episode_features(kpms, alloc), want)
+    assert ((want[..., 15] >= 0) & (want[..., 15] <= 1)).all()
+
+
+@pytest.mark.parametrize("off", ["reciprocal", -2, -1, 1, 2])
+def test_rounded_quotient_restores_numpy_division(off):
+    """From a quotient an ulp or two off — or formed as XLA's TPU backend
+    forms it, times the divisor's reciprocal — ``rounded_quotient`` gives
+    NumPy's correctly rounded f32 division, over normalized KPMs and over
+    values from 1e-3 to 1e3 of either sign."""
+    rng = np.random.default_rng(11)
+    s = kpmmod.KPM_SCALE
+    ep = episode(64, T=26, seed=31)
+    mag = np.float32(10) ** rng.integers(-3, 4, (20000, 15))
+    for d in (ep.kpms.reshape(-1, 15) - kpmmod.KPM_CENTER,
+              (rng.normal(size=(20000, 15)) * mag).astype(np.float32)):
+        want = d / s
+        if off == "reciprocal":
+            q = d * (np.float32(1) / s)
+            assert (q != want).mean() > 0.05  # the case it exists for
+        else:
+            q = want
+            for _ in range(abs(off)):
+                q = np.nextafter(q, np.float32(np.sign(off) * np.inf))
+        got = rounded_quotient(jnp.asarray(q), jnp.asarray(d),
+                               jnp.asarray(s))
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_report_features_iq_columns():
+    """``include_iq`` columns through the device path: ``iq_columns`` as
+    the ``extra`` operand lands after channel 15, bit-equal to the host
+    concatenation, and ``episode_features`` with ``iq`` is the same."""
+    ep = episode(3, T=5, iq=True)
+    want = _host_features(ep.kpms, ep.alloc_ratio, ep.iq)
+    n, s = ep.kpms.shape[:2]
+    got = report_features(
+        (n, s), jnp.asarray(ep.kpms.reshape(-1)),
+        jnp.asarray(report_alloc(ep.alloc_ratio, s)),
+        kpmmod.KPM_CENTER, kpmmod.KPM_SCALE,
+        jnp.asarray(iq_columns(ep.iq, s)))
+    assert got.shape == (3, 5 + WINDOW, 16 + N_IQ_FEATS)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        episode_features(ep.kpms, ep.alloc_ratio, ep.iq), want)
+
+
+def _in_range(params):
+    """The readout scaled and shifted so the estimates land inside the
+    1..130 Mbps clip (a random readout gives about -0.6..0.5 Mbps, which
+    the clip maps to one value)."""
+    head = params["head"]
+    return dict(params, head=dict(head, w2=head["w2"] * 50.0,
+                                  b2=head["b2"] + 60.0))
+
+
+def _manual_estimate(ep, c, params, chunk):
+    """Host features -> ``ssm_forward_seq`` per ``chunk`` UEs -> the
+    WINDOW-1 alignment slice -> policy reduce -> clip."""
+    feats = _host_features(ep.kpms, ep.alloc_ratio,
+                           ep.iq if c.include_iq else None)
+    est = []
+    for i in range(0, ep.n_ues, chunk):
+        fc, _ = ssm_forward_seq(c, params, jnp.asarray(feats[i:i + chunk]))
+        est.append(reduce_forecasts(
+            c, np.asarray(fc[:, WINDOW - 1:WINDOW - 1 + ep.n_steps])))
+    return np.clip(np.concatenate(est), 1.0, 130.0)
+
+
+@pytest.mark.parametrize("iq", [False, True])
+def test_estimate_fleet_ssm_across_chunks(iq, monkeypatch):
+    """With ``SSM_CHUNK_UES`` lowered to cut the fleet into two whole
+    chunks and a short one, the engine's device-built features give the
+    manual sequence pass over host features, bit for bit."""
+    monkeypatch.setattr(engine, "SSM_CHUNK_UES", 4)
+    c, params = tiny_ssm(seed=4, forecast_horizon=2, forecast_policy="min",
+                         include_iq=iq)
+    params = _in_range(params)
+    ep = episode(10, T=6, seed=21, iq=iq)
+    got = estimate_fleet(ep, (c, params))
+    assert ((got > 1.0) & (got < 130.0)).all()
+    np.testing.assert_array_equal(got, _manual_estimate(ep, c, params, 4))
+
+
+@pytest.mark.parametrize("sched", [None, "pf"])
+def test_pool_ssm_matches_manual_across_chunks(sched, monkeypatch):
+    """A churned pool with the recurrent estimator, its sessions cut into
+    several chunks, == the same pool fed the manual estimates over host
+    features: identical splits, estimates, occupancy and shares."""
+    monkeypatch.setattr(engine, "SSM_CHUNK_UES", 6)
+    rng = np.random.default_rng(23)
+    schedule = sc.make_churn_schedule(
+        sc.ChurnConfig(arrival_rate=3.0, mean_dwell=4.0, max_dwell=6), 10,
+        rng)
+    m = schedule.n_sessions
+    assert m > 6 and m % 6
+    names = np.asarray(sc.SCENARIOS)[np.arange(m) % len(sc.SCENARIOS)]
+    sessions = sc.gen_episode_batch(names, schedule.max_dwell, rng,
+                                    include_iq=False)
+    c, params = tiny_ssm(seed=6)
+    params = _in_range(params)
+    prof = vgg_split_profile(FULL)
+    kw = dict(churn=schedule, capacity=8, estimator=(c, params))
+    if sched:
+        kw.update(sched=SchedulerConfig(policy=sched), n_cells=2,
+                  cell_idx=(np.arange(m) % 2).astype(np.int32))
+    run = lambda: simulate_fleet(sessions, fig6_style_table(prof), prof,
+                                 ControllerConfig(0.5, 2, 3), **kw)
+    got = run()
+    want_est = _manual_estimate(sessions, c, params, 6)
+    assert ((want_est > 1.0) & (want_est < 130.0)).all()
+    monkeypatch.setattr(engine, "estimate_fleet",
+                        lambda ep, est, **_: want_est)
+    want = run()
+    for f in ("splits", "est_tp", "active", "prb_share", "delay_s"):
+        x, y = getattr(got, f), getattr(want, f)
+        if x is None:
+            assert y is None, f
+        else:
+            np.testing.assert_array_equal(x, y, err_msg=f)
+    assert got.active.any()
 
 
 def test_step_scan_matches_sequence():

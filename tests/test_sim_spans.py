@@ -2,9 +2,11 @@
 fleet, the slot pool with and without PF, and the mesh serving path,
 every stage span appears, nested as documented (``ingest`` inside the
 stage whose inputs it copies, the readback inside ``estimator_fwd`` /
-``controller``); each ``ingest`` span's ``bytes`` stat is the ``nbytes``
-of what the shapes say it copied; and results are bit-identical with the
-session open and without one."""
+``controller``; on the recurrent path ``featurize`` copies each chunk's
+raw reports in and ``estimator_fwd`` follows it, chunk by chunk); each
+``ingest`` span's ``bytes`` stat is the ``nbytes`` of what the shapes say
+it copied; and results are bit-identical with the session open and
+without one."""
 import jax
 import numpy as np
 import pytest
@@ -19,6 +21,7 @@ from repro.estimator.ssm import SSMConfig, init_ssm
 from repro.models.vgg import FULL, vgg_split_profile
 from repro.sim import (SchedulerConfig, estimate_fleet, make_serving_mesh,
                        simulate_fleet)
+from repro.sim import engine
 from repro.sim import telemetry as tel
 
 CALL = "test_call"  # the test's own span around one call
@@ -27,6 +30,12 @@ STAGES = {"featurize", "ingest", "estimator_fwd", "controller", "gather",
 CFG = ControllerConfig(ewma_alpha=0.5, hysteresis_steps=2, fallback_split=3)
 N_SC = 16
 F32 = 4  # bytes of one float32 / int32
+SSM_CHUNK = 16  # sessions per recurrent pass here: several chunks per call
+
+
+@pytest.fixture(autouse=True)
+def _ssm_chunks(monkeypatch):
+    monkeypatch.setattr(engine, "SSM_CHUNK_UES", SSM_CHUNK)
 
 
 @pytest.fixture(scope="module")
@@ -69,7 +78,8 @@ def _churn():
 
 
 def _setup(kind):
-    """(call, stage ingest bytes the shapes give, top-level stage order)."""
+    """(call, ingest bytes the shapes give per parent stage, top-level
+    stage order with repeats of one stage merged)."""
     if kind == "fleet":
         ep = _fleet()
         n, t = ep.n_ues, ep.n_steps
@@ -86,14 +96,18 @@ def _setup(kind):
     if kind == "pool_pf":
         kw.update(sched=SchedulerConfig(policy="pf"), n_cells=2,
                   cell_idx=(np.arange(m) % 2).astype(np.int32))
-    feats = m * sessions.kpms.shape[1] * 16 * F32
+    # raw reports and each report's alloc ratio; the features are made on
+    # the device
+    reports = m * sessions.kpms.shape[1] * (15 + 1) * F32
     # shared table row, warm split, true tp, cell, dwell, arrival, est,
     # ready_end
     ctl = (41 + 1 + m * el + 3 * m + m * el + schedule.horizon) * F32
+    chunks = -(-m // SSM_CHUNK)
+    assert chunks >= 2
     return ((lambda p, tb: simulate_fleet(sessions, tb, p, CFG, **kw)),
-            {"estimator_fwd": feats, "controller": ctl},
-            ["featurize", "estimator_fwd", "controller", "gather",
-             "split_metrics"])
+            {"featurize": reports, "controller": ctl},
+            ["featurize", "estimator_fwd"] * chunks
+            + ["controller", "gather", "split_metrics"])
 
 
 def _traced(tmp_path, fn):
@@ -138,16 +152,17 @@ KINDS = ["fleet", "pool", "pool_pf"]
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_stage_spans_nest(kind, prof, table, tmp_path):
-    call, _, order = _setup(kind)
+    call, want, order = _setup(kind)
     _, tree = _traced(tmp_path, lambda: call(prof, table))
     top = [n for n, parent, _ in tree if parent == CALL]
-    # one featurize, estimator chunks, then each later stage once
+    # windowed: one featurize, estimator chunks; recurrent: featurize and
+    # estimator_fwd per chunk; then each later stage once
     assert [n for i, n in enumerate(top) if i == 0 or n != top[i - 1]] \
         == order
     assert top.count("controller") == 1
     parents = {(n, parent) for n, parent, _ in tree if parent != CALL}
-    assert parents == {("ingest", "estimator_fwd"), ("ingest", "controller")}
-    for stage in ("estimator_fwd", "controller"):  # one copy-in per call
+    assert parents == {("ingest", stage) for stage in want}
+    for stage in want:  # one copy-in per call
         assert sum(1 for n, p, _ in tree if p == stage) \
             == top.count(stage)
 
