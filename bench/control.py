@@ -84,7 +84,8 @@ def main(argv=None) -> int:
         if seed in args.control:
             rows = [check.numbers(hz, control_results(st, hz, cfg["control"]),
                                   bench.expectations(st, hz), st.tgt, cfg,
-                                  hz.scheduler) for hz in st.horizons]
+                                  hz.scheduler, st.cell.target,
+                                  st.target_params) for hz in st.horizons]
             print(json.dumps({"workload": args.workload, "seed": seed,
                               "side": "control", **check.worst(rows)}),
                   flush=True)

@@ -22,6 +22,10 @@ precision or above it passes, one below it does not.
                 energy differs from the reference's split metrics at the
                 program's own splits and PRB shares. Exact (float64).
 
+A split target whose tail the edge serves adds its own numbers (its
+``names``), the tail's gaps to the target's own plain reference at the
+program's splits, from its ``check`` hook (``harness/splitting.py``).
+
 The gap's median and 99th percentile, the share of estimates more than
 one table bucket (1 Mbps) off, and each against every precision apart, are
 reported beside them (by ``bench/control.py``), not compared.
@@ -33,10 +37,16 @@ import numpy as np
 from harness import reference as ref
 
 TP_CLIP = (1.0, 130.0)
-# the numbers a cell's limits file may name (bench/limits/<cell>.json)
+# the numbers a cell's limits file may name (bench/limits/<cell>.json),
+# with its split target's ``names``
 NAMES = ("est_gap_mbps", "splits_off", "metrics_off")
 FAR = 1e300  # a reading that fails every limit and still prints as JSON
 BUCKET_MBPS = 1.0  # the lookup table's bucket: the controller rounds to it
+
+
+def names(target) -> tuple:
+    """The numbers a cell whose split target is ``target`` may limit."""
+    return NAMES + tuple(getattr(target, "names", ()))
 
 
 def reference_outputs(hz, raw, tgt, config, sched):
@@ -83,8 +93,11 @@ def gaps(result, expects: dict) -> dict:
     return out
 
 
-def numbers(hz, result, expects, tgt, config, sched) -> dict:
-    """Every number of one program result (see the module's docstring)."""
+def numbers(hz, result, expects, tgt, config, sched, target=None,
+            target_params=None) -> dict:
+    """Every number of one program result (see the module's docstring);
+    ``target``: the split target's module, whose ``check`` hook adds the
+    served tail's numbers."""
     ctl = config["controller"]
     est_p = np.asarray(result.est_tp, np.float64)
     act = (np.ones(est_p.shape, bool) if result.active is None
@@ -112,8 +125,13 @@ def numbers(hz, result, expects, tgt, config, sched) -> dict:
                | (np.asarray(result.delay_s) != d)
                | (np.asarray(result.privacy) != p)
                | (np.asarray(result.energy_j) != e))
-    return {**gaps(result, expects), "splits_off": splits_off,
-            "metrics_off": int(differs[act].sum())}
+    out = {**gaps(result, expects), "splits_off": splits_off,
+           "metrics_off": int(differs[act].sum())}
+    tail = getattr(target, "check", None)
+    if tail is not None:
+        out.update(tail(config["split_target"], target_params, hz,
+                        hz.payload, result))
+    return out
 
 
 def worst(rows) -> dict:
@@ -123,5 +141,5 @@ def worst(rows) -> dict:
 
 
 def verdict(nums: dict, limits: dict) -> bool:
-    """Every number the cell's limits name is within its limit."""
-    return all(nums[k] <= v for k, v in limits.items())
+    """Every number the cell's limits name is there and within its limit."""
+    return all(k in nums and nums[k] <= v for k, v in limits.items())

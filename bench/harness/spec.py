@@ -4,8 +4,15 @@
   bench/configs/<config>.py     its plain reference, weights and cost
   bench/traffic/<traffic>.json  the traffic mix's parameters
   bench/metrics/<metric>.py     the reader of one per-layer metric
+  bench/targets/<model>.py      the split target a configuration's
+                                ``split_target.model`` names: its profile
+                                and, where the edge serves its tail, the
+                                served-tail hooks (``harness/splitting.py``)
+  bench/limits/<cell>.json      the limits of the numbers ``correct``
+                                compares (``harness/check.py``)
 
-A new cell, mix or metric is new files and entries, never an edit here.
+A new cell, mix, metric or split target is new files and entries, never an
+edit here.
 """
 from __future__ import annotations
 
@@ -38,6 +45,7 @@ class Cell:
     chips: int
     config: dict  # the configuration's file, tiny overrides applied
     ref: object  # its reference module
+    target: object  # its split target's module
     mix: dict  # the traffic mix's file, tiny overrides applied
     end_to_end: list  # this cell's end-to-end metrics
     per_layer: list  # (metric entry, reader module) of this cell
@@ -67,7 +75,10 @@ def cell(name: str, *, tiny: bool = False, root: pathlib.Path = ROOT) -> Cell:
         config.update(config.get("tiny", {}))
         mix.update(mix.get("tiny", {}))
     ref = load_module(bdir / "configs" / f"{w['config']}.py")
+    target = load_module(bdir / "targets"
+                         / f"{config['split_target']['model']}.py")
     e2e = [m for m in bench["end_to_end"] if _applies(m, name)]
     layer = [(m, load_module(bdir / "metrics" / f"{m['name']}.py"))
              for m in bench["per_layer"] if _applies(m, name)]
-    return Cell(name, int(w["chips"]), config, ref, mix, e2e, layer)
+    return Cell(name, int(w["chips"]), config, ref, target, mix, e2e,
+                layer)

@@ -1,12 +1,37 @@
-"""The split target every configuration partitions: VGG16's 43 split points
-(paper arXiv:2509.01906, Fig. 5), their analytic FLOPs and activation
-bytes, the paper's dCor privacy curve, and the PSO lookup table of
-Algorithm 1 (one objective evaluation over every split and integer Mbps).
+"""What every split target shares: the per-split delays and UE energy
+from its profile and the configuration's devices, and the PSO lookup table
+of Algorithm 1 (one objective evaluation over every split and integer Mbps).
 
-A copy of the program's ``models.vgg`` profile, ``core.objective``,
-``core.energy`` and ``core.pso``, so that the profile and the table the
-program is handed, and the reference's split metrics, are made here and not
-by the code under test.
+A configuration's ``split_target.model`` names its module,
+``bench/targets/<model>.py``, which supplies
+
+  profile(spec) -> (flops_head, data_bytes, privacy)
+                   cumulative head FLOPs, boundary bytes and dCor per split
+  name(spec)    -> the name the program's ``SplitProfile`` carries
+
+where ``spec`` is the ``split_target`` block. A target whose tail the edge
+serves inside the fleet path may add hooks; the harness calls each only
+where the module has it, and none inside the measured window except
+``call_kwargs``:
+
+  init_params(spec, key)          its seeded weights, made on the device in
+                                  set-up after the estimator's
+  payload(spec, params, target_mix, hz, rng)
+                                  per horizon, what the UEs send, from the
+                                  mix's optional ``target`` block; kept
+                                  with the horizon as ``hz.payload``
+  call_kwargs(spec, params, payload) -> dict
+                                  merged into ``simulate_fleet``'s keyword
+                                  arguments on every call
+  names                           a tuple: the numbers ``check`` returns,
+                                  which a cell's limits file may name
+  check(spec, params, hz, payload, result) -> {name: number}
+                                  the tail's gaps to the target's own plain
+                                  reference, at the program's own splits
+
+A copy of the program's ``core.objective``, ``core.energy`` and
+``core.pso``, so that the table the program is handed, and the reference's
+split metrics, are made here and not by the code under test.
 """
 from __future__ import annotations
 
@@ -16,81 +41,20 @@ NO_SPLIT = -1
 TP_FLOOR_BPS = 1.0  # a starved link's delay stays finite
 PRB_FLOOR_MBPS = 0.01
 
-_FEATURES = [("conv", 64), ("relu", 0), ("conv", 64), ("relu", 0),
-             ("pool", 2), ("conv", 128), ("relu", 0), ("conv", 128),
-             ("relu", 0), ("pool", 2)]
-_FEATURES += [("conv", 256), ("relu", 0)] * 3 + [("pool", 2)]
-_FEATURES += ([("conv", 512), ("relu", 0)] * 3 + [("pool", 2)]) * 2
 
-
-def vgg16_ops(num_classes):
-    ops = [("input", 0)] + _FEATURES + [("avgpool", 7), ("flatten", 0)]
-    ops += [("fc", 4096), ("relu", 0), ("dropout", 0),
-            ("fc", 4096), ("relu", 0), ("dropout", 0),
-            ("fc", num_classes), ("softmax", 0), ("output", 0)]
-    assert len(ops) == 43
-    return ops
-
-
-def vgg16_profile(image_size, num_classes, bytes_per_el, in_channels=3):
-    """(flops_head cumulative, data_bytes) per split point."""
-    h = w = image_size
-    c = c_in = in_channels
-    flat = flat_in = None
-    flops, data = [], []
-    for kind, arg in vgg16_ops(num_classes):
-        if kind == "conv":
-            c = arg
-            flops.append(2 * 9 * c_in * c * h * w)
-            c_in = c
-        elif kind == "pool":
-            h //= arg
-            w //= arg
-            flops.append(float(h * w * c))
-        elif kind == "avgpool":
-            h = w = min(h, arg)
-            flops.append(float(h * w * c))
-        elif kind == "flatten":
-            flat = flat_in = h * w * c
-            flops.append(0.0)
-        elif kind == "fc":
-            flat = arg
-            flops.append(2 * flat_in * flat)
-            flat_in = flat
-        elif kind in ("relu", "softmax"):
-            flops.append(float(flat if flat is not None else h * w * c))
-        else:
-            flops.append(0.0)
-        size = flat if flat is not None else h * w * c
-        data.append(float(size) * bytes_per_el)
-    return np.cumsum(flops).astype(float), np.array(data)
-
-
-def paper_privacy():
-    """dCor(input, activation) per split, the paper's Fig. 5b curve."""
-    l = np.arange(1, 44)
-    p = 0.97 - 0.45 * (l / 43) ** 1.5 - 0.30 / (1.0 + np.exp(-(l - 24.5)
-                                                             * 1.5))
-    p = np.clip(p, 0.2, 1.0)
-    p[0], p[24], p[37], p[42] = 1.0, 0.215, 0.220, 0.210
-    return p
-
-
-def split_target(spec):
-    """Per-split arrays and the lookup table for a config's
-    ``split_target`` block."""
-    if spec["model"] != "vgg16":
-        raise ValueError(f"unknown split target {spec['model']!r}")
-    flops_head, data = vgg16_profile(spec["image_size"], spec["num_classes"],
-                                     spec["bytes_per_el"])
+def split_target(spec, target):
+    """Per-split arrays, the profile's name and the lookup table for a
+    config's ``split_target`` block, profiled by its module ``target``."""
+    flops_head, data, privacy = (np.asarray(a, float)
+                                 for a in target.profile(spec))
     ue, edge = spec["ue"], spec["edge"]
     d_ue = flops_head / ue["flops_per_s"]
     d_ser = edge["fixed_latency_s"] + (flops_head[-1] - flops_head) / edge[
         "flops_per_s"]
     e_ue = ue["tdp_w"] / ue["threads"] * d_ue
-    privacy = paper_privacy()
-    tgt = dict(flops_head=flops_head, data_bytes=data, privacy=privacy,
-               d_ue=d_ue, d_ser=d_ser, e_ue=e_ue)
+    tgt = dict(name=target.name(spec), flops_head=flops_head,
+               data_bytes=data, privacy=privacy, d_ue=d_ue, d_ser=d_ser,
+               e_ue=e_ue)
     tgt["table"] = pso_table(tgt, spec)
     return tgt
 

@@ -36,10 +36,13 @@ def estimator_config(config: dict):
 
 class Program:
     """What one cell hands the program: the split target's profile and
-    table, the controller settings, the device profiles and, per horizon,
-    the keyword arguments of ``simulate_fleet``."""
+    table, the controller settings, the device profiles, the serving mesh
+    the mix names (``serving``, a ``DxM`` string such as ``"4x1"``) and,
+    per horizon, the keyword arguments of ``simulate_fleet``, with those
+    the split target's ``call_kwargs`` hook adds."""
 
-    def __init__(self, config: dict, tgt: dict):
+    def __init__(self, config: dict, tgt: dict, mix: dict, target=None,
+                 target_params=None):
         self.sim = import_program()
         from repro.core.controller import ControllerConfig
         from repro.core.energy import DeviceProfile
@@ -47,17 +50,26 @@ class Program:
         from repro.core.pso import LookupTable
         st = config["split_target"]
         self.profile = SplitProfile(
-            name=f"{st['model']}-{st['image_size']}px",
-            flops_head=tgt["flops_head"], data_bytes=tgt["data_bytes"],
-            privacy=tgt["privacy"],
+            name=tgt["name"], flops_head=tgt["flops_head"],
+            data_bytes=tgt["data_bytes"], privacy=tgt["privacy"],
             layer_names=[str(i) for i in range(len(tgt["flops_head"]))])
         self.table = LookupTable(self.profile.name, tgt["table"], None, None)
         self.ctl = ControllerConfig(**config["controller"])
         self.ue, self.edge = (DeviceProfile(**st[k]) for k in ("ue", "edge"))
         self.ecfg = estimator_config(config)
+        self.serving = (self.sim.make_serving_mesh(mix["serving"])
+                        if mix.get("serving") else None)
+        self.split_spec = st
+        self.call_kwargs = getattr(target, "call_kwargs", None)
+        self.target_params = target_params
 
     def kwargs(self, hz, **extra) -> dict:
         kw = dict(ue=self.ue, server=self.edge, **extra)
+        if self.serving is not None:
+            kw.update(serving=self.serving)
+        if self.call_kwargs is not None:
+            kw.update(self.call_kwargs(self.split_spec, self.target_params,
+                                       hz.payload))
         if hz.kind == "pool":
             kw.update(churn=hz.schedule, capacity=hz.capacity,
                       n_cells=hz.n_cells)

@@ -12,6 +12,12 @@ Kinds of mix:
          dwell of mean ``mean_dwell`` periods; sessions attach to cells
          round-robin on a ring, optionally coupled (``coupling: "ring"``),
          under ``scheduler`` (null, or the PF scheduler's settings).
+
+Keys any mix may add:
+  serving: a ``DxM`` mesh string (``"4x1"``): ``simulate_fleet`` serves the
+           estimator on ``repro.sim.make_serving_mesh(serving)``.
+  target:  the split target's parameters, read by its ``payload`` hook
+           (``harness/splitting.py``) in set-up, never here.
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ class Horizon:
     capacity: int = 0
     n_cells: int = 1
     scheduler: dict | None = None  # {"policy": "pf", "pf_beta", "eps", ...}
+    payload: object = None  # what the UEs send the split target's tail
 
     @property
     def shape(self):

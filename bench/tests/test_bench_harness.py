@@ -36,7 +36,8 @@ def test_cell_resolves_to_its_files(name):
         assert callable(getattr(cell.ref, fn))
     assert cell.mix["kind"] in ("fleet", "pool")
     limits = bench.limits_of(name)
-    assert {"splits_off", "metrics_off"} <= set(limits) <= set(check.NAMES)
+    assert {"splits_off", "metrics_off"} <= set(limits) <= set(
+        check.names(cell.target))
     assert limits["splits_off"] == limits["metrics_off"] == 0  # exact
     assert {m["name"] for m in cell.end_to_end} >= {"ue_periods_per_s",
                                                     "setup_s"}
