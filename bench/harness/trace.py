@@ -1,8 +1,8 @@
 """Reduce a profiler trace to what the per-layer metrics read: the window
 the harness's spans cover, each device's busy time in it (the union of the
 intervals in which an XLA op ran), the busy time inside each jit module's
-executions, the time of each op, the host spans, and the device's idle
-gaps named by the innermost host span they fell in.
+executions on the devices that ran it, the time of each op, the host spans,
+and the device's idle gaps named by the innermost host span they fell in.
 
 ``load`` reads the newest ``*.xplane.pb`` under a trace directory into
 plain tuples; ``reduce`` works on those, so a test can feed it a recorded
@@ -115,8 +115,10 @@ class Reduced:
     window_s: float
     busy_s: float  # mean over the devices used
     n_spans: int  # harness spans (calls) in the window
-    module_s: dict  # module -> busy device seconds in its executions
-    module_calls: dict  # module -> executions (first device)
+    module_s: dict  # module -> busy device seconds in its executions,
+    # mean over the devices that ran it
+    module_calls: dict  # module -> executions (first device that ran it)
+    module_devices: dict  # module -> devices that ran it
     op_s: dict  # op -> device seconds
     gap_s: dict  # host span -> idle device seconds
     host_s: dict  # host span name -> seconds
@@ -135,7 +137,9 @@ class Reduced:
 
 
 def reduce(tr: Trace, *, span: str, n_devices: int) -> Reduced:
-    """Every device number is the mean over the first ``n_devices``."""
+    """Busy, op and gap times are means over the first ``n_devices``; a
+    module's time is the mean over those of them that ran it (a program on
+    one chip of four keeps its own time)."""
     calls = [(s, e) for n, s, e in tr.host if n == span]
     if not calls:
         raise ValueError(f"no {span!r} host spans in the trace")
@@ -150,7 +154,8 @@ def reduce(tr: Trace, *, span: str, n_devices: int) -> Reduced:
     used = [tr.devices[n] for n in names[:n_devices]]
     k = max(len(used), 1) * 1e9
     busy, module_s, op_s, gap_s, module_calls = 0.0, {}, {}, {}, {}
-    for i, dev in enumerate(used):
+    module_devices = {}
+    for dev in used:
         ops = [(n, max(s, lo), min(e, hi)) for n, s, e in dev["ops"]
                if e > lo and s < hi]
         for n, s, e in ops:
@@ -162,10 +167,14 @@ def reduce(tr: Trace, *, span: str, n_devices: int) -> Reduced:
         if mods and len(ms):
             got = covered(ms, me, [s for _, s, _ in mods],
                           [e for _, _, e in mods])
+            here = {m for m, _, _ in mods}
+            first = here - set(module_devices)
             for (m, _, _), v in zip(mods, got):
-                module_s[m] = module_s.get(m, 0.0) + float(v) / k
-                if i == 0:
+                module_s[m] = module_s.get(m, 0.0) + float(v) / 1e9
+                if m in first:
                     module_calls[m] = module_calls.get(m, 0) + 1
+            for m in here:
+                module_devices[m] = module_devices.get(m, 0) + 1
         g0 = np.concatenate([[lo], me])
         g1 = np.concatenate([ms, [hi]])
         keep = g1 > g0
@@ -174,6 +183,8 @@ def reduce(tr: Trace, *, span: str, n_devices: int) -> Reduced:
         for j, d in zip(at, (g1 - g0)[keep]):
             who = b_name[j] if j >= 0 else OTHER
             gap_s[who] = gap_s.get(who, 0.0) + float(d) / k
+    module_s = {m: v / module_devices[m] for m, v in module_s.items()}
     return Reduced(window_s=(hi - lo) / 1e9, busy_s=busy, n_spans=len(calls),
-                   module_s=module_s, module_calls=module_calls, op_s=op_s,
-                   gap_s=gap_s, host_s=host_s)
+                   module_s=module_s, module_calls=module_calls,
+                   module_devices=module_devices, op_s=op_s, gap_s=gap_s,
+                   host_s=host_s)

@@ -35,6 +35,28 @@ def test_control_is_not_correct(name):
     assert not check.verdict(nums, limits), (nums, limits)
 
 
+def test_control_call_carries_the_mix_serving(monkeypatch):
+    """The int8 control of the mesh cell goes through ``Program.call``
+    and so through the mix's serving mesh: the call carries both
+    ``serving`` and ``quant``, and gives the whole fleet's estimates."""
+    import repro.sim as sim
+    st = bench.setup("lstm.mesh4.900", SEED, tiny=True, require_chip=False)
+    assert st.prog.serving is not None
+    seen, real = [], sim.simulate_fleet
+
+    def spy(*a, **k):
+        seen.append(k)
+        return real(*a, **k)
+
+    monkeypatch.setattr(sim, "simulate_fleet", spy)
+    hz = st.horizons[0]
+    got = control.control_results(st, hz, st.cell.config["control"])
+    assert len(seen) == 1
+    assert seen[0]["serving"] is st.prog.serving
+    assert seen[0]["quant"] == "int8"
+    assert got.est_tp.shape == hz.episode.tp_mbps.shape
+
+
 def _altered_answer(sim):
     real = sim.simulate_fleet
 

@@ -144,7 +144,7 @@ def test_tpu_trace_readers(monkeypatch, tmp_path):
         assert reader.read(ctx) > 0, name
 
 
-def _ingest_bytes(cfg, hz):
+def _ingest_bytes(cfg, hz, serving):
     """Bytes one horizon copies to the device, from the shapes."""
     ep = hz.episode
     row = cfg["split_target"]["tp_max_mbps"] + 1  # one PSO lookup row
@@ -155,8 +155,11 @@ def _ingest_bytes(cfg, hz):
         return 4 * (feats + row + 1 + 2 * m * el + 3 * m
                     + hz.schedule.horizon)
     n, t = ep.n_ues, ep.n_steps
-    rows = cfg["window"] * cfg["n_kpms"] + int(np.prod(ep.iq.shape[2:])) + 1
-    return 4 * (n * t * rows + n * row + 1 + n * t)
+    rows = cfg["window"] * cfg["n_kpms"] + int(np.prod(ep.iq.shape[2:]))
+    # the PRB ratio goes with each row, or once per UE on the mesh path;
+    # then the controller's table rows, warm split and estimates
+    alloc = n if serving else n * t
+    return 4 * (n * t * rows + alloc + n * row + 1 + n * t)
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in
@@ -175,6 +178,7 @@ def test_traced_run_reads_host_spans(name):
     # one cell's calls cycle its horizons
     st = bench.setup(name, SEED, tiny=True, require_chip=False)
     n_h, calls = len(st.horizons), out["attempted"]
-    want = sum(_ingest_bytes(st.cell.config, st.horizons[i % n_h])
+    want = sum(_ingest_bytes(st.cell.config, st.horizons[i % n_h],
+                             st.cell.mix.get("serving"))
                for i in range(calls)) / calls / 1e6
     assert out["metrics"]["ingest.mb"]["value"] == pytest.approx(want)

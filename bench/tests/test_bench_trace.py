@@ -36,6 +36,7 @@ def test_reduce_made_trace():
     assert r.busy_s == pytest.approx(45 * NS)
     assert r.module_s == pytest.approx({"fwd": 15 * NS, "sweep": 20 * NS})
     assert r.module_calls == {"fwd": 1, "sweep": 1}
+    assert r.module_devices == {"fwd": 1, "sweep": 1}
     assert r.op_s == pytest.approx({"conv": 10 * NS, "dot": 10 * NS,
                                     "while": 20 * NS, "late": 10 * NS})
     # [0, 40) mid 20 in featurize; [55, 150) mid 102.5 and [170, 190) in
@@ -101,3 +102,137 @@ def test_reduce_a_tpu_trace():
                  "control_scan.device_ms"):
         reader = spec.load_module(BENCH / "metrics" / f"{name}.py")
         assert reader.read(ctx) > 0, name
+
+
+def _mesh():
+    """Four chips. The mesh forward runs on all four, a little longer on
+    each later chip; the controller's sweep on device 0 alone, a copy on
+    device 2 alone."""
+    host = [("bench_horizon", 0, 100), ("bench_horizon", 100, 200)]
+    devices = {}
+    for d in range(4):
+        ops = [("conv", 10, 30 + d), ("conv", 110, 120)]
+        mods = [("jit_fwd_sharded(3)", 10, 30 + d),
+                ("jit_fwd_sharded(3)", 110, 130)]
+        if d == 0:
+            ops.append(("while", 50, 90))
+            mods.append(("jit_sweep(9)", 50, 90))
+        if d == 2:
+            ops.append(("copy", 150, 160))
+            mods.append(("jit_copy(4)", 150, 160))
+        devices[f"/device:TPU:{d}"] = {"ops": ops, "modules": mods}
+    return trace.Trace(devices, host)
+
+
+def test_reduce_mesh_trace():
+    """A module's time is the mean over the devices that ran it; busy, op
+    and gap times stay means over all four."""
+    r = trace.reduce(_mesh(), span="bench_horizon", n_devices=4)
+    assert r.module_devices == {"fwd_sharded": 4, "sweep": 1, "copy": 1}
+    # fwd_sharded: (20 + d) + 10 on device d; sweep and copy their own
+    assert r.module_s == pytest.approx({"fwd_sharded": 31.5 * NS,
+                                        "sweep": 40 * NS, "copy": 10 * NS})
+    # executions counted on the first device that ran each module
+    assert r.module_calls == {"fwd_sharded": 2, "sweep": 1, "copy": 1}
+    # busy 70, 31, 42, 33 ns on the four devices
+    assert r.busy_s == pytest.approx(44 * NS)
+    assert r.op_s == pytest.approx({"conv": 31.5 * NS, "while": 10 * NS,
+                                    "copy": 2.5 * NS})
+    assert sum(r.gap_s.values()) == pytest.approx(r.window_s - r.busy_s)
+
+
+def test_readers_on_mesh_trace():
+    """The estimator readers find the mesh forward, the scan reader the
+    sweep at its own time, and the roofline reads the replicated weights
+    once per execution on each of the four chips."""
+    import types
+
+    from harness import spec
+    r = trace.reduce(_mesh(), span="bench_horizon", n_devices=4)
+    calls = []
+
+    def estimator_cost(c, shape, n):  # 1 GFLOP, 1 MB of rows, 1 kB weights
+        calls.append(n)
+        return 1e9, 1e6 + 1e3 * n
+
+    ctx = types.SimpleNamespace(
+        red=r, config={}, ref=types.SimpleNamespace(
+            estimator_cost=estimator_cost),
+        peaks={"bf16_flops": 1e15, "hbm_bytes_per_s": 1e9}, chips=4,
+        horizons=[types.SimpleNamespace(shape={})], log=lambda s: None)
+
+    def read(name):
+        return spec.load_module(BENCH / "metrics" / f"{name}.py").read(ctx)
+
+    assert read("estimator_fwd.device_ms") == pytest.approx(31.5e-6 / 2)
+    assert read("control_scan.device_ms") == pytest.approx(40e-6 / 2)
+    assert read("device_idle") == pytest.approx(100 * (1 - 44 / 200))
+    roof = read("estimator_fwd_roofline")
+    assert calls == [4, 4]  # 1 execution per call, on 4 chips
+    # 2 x (1 MB + 4 kB) over 4 chips' HBM, against 31.5 ns per chip
+    assert roof == pytest.approx(100 * (2 * 1.004e6 / 4e9) / (31.5 * NS))
+
+
+# each listed reader's value on the committed TPU traces, read with the
+# one-chip rules (module time over all devices, weights once per
+# execution): with one chip the per-device rules give the same numbers.
+# Only the readers named here are read; a reader added later is not held
+# to these traces.
+PINNED = {
+    "tpu_solo": {
+        "control_scan.device_ms": 37.431483,
+        "control_scan.host_ms": 0.0,
+        "device_idle": 95.08736626068155,
+        "episode_features.host_ms": 0.0,
+        "estimator_fwd.device_ms": 36.699960999999995,
+        "estimator_fwd.host_ms": 7.3702689999999995,
+        "estimator_fwd_roofline": 0.8305815269370355,
+        "featurize.host_ms": None,
+        "gather.host_ms": 0.0,
+        "idle_unattributed": 94.60704696101864,
+        "ingest.host_ms": 0.0,
+        "ingest.mb": 0.0,
+        "mfu": 0.0013034022424794583,
+        "split_metrics.host_ms": 0.0,
+    },
+    "tpu_solo_spans": {
+        "control_scan.device_ms": 37.442814,
+        "control_scan.host_ms": 76.064818,
+        "device_idle": 94.78405061523976,
+        "episode_features.host_ms": 1200.2949800000001,
+        "estimator_fwd.device_ms": 36.783338,
+        "estimator_fwd.host_ms": 108.313007,
+        "estimator_fwd_roofline": 0.8286988430987327,
+        "featurize.host_ms": 1200.2949800000001,
+        "gather.host_ms": 18.64649,
+        "idle_unattributed": 0.22941226755314606,
+        "ingest.host_ms": 8.722581,
+        "ingest.mb": 250.528324,
+        "mfu": 0.0013821390652417529,
+        "split_metrics.host_ms": 9.58688,
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_readers_on_tpu_traces_are_pinned(name, tmp_path, monkeypatch):
+    import shutil
+    import types
+
+    from harness import bench, peaks, spec
+    src = BENCH / "tests" / f"{name}.xplane.pb"
+    (tmp_path / "cell").mkdir()
+    shutil.copy(src, tmp_path / "cell" / "host.xplane.pb")
+    monkeypatch.setattr(bench, "TRACE_DIR", tmp_path)
+    cell = spec.cell("ssm.pool16k.solo")
+    ctx = types.SimpleNamespace(
+        red=trace.reduce(trace.load_file(src), span=bench.SPAN,
+                         n_devices=1),
+        config=cell.config, ref=cell.ref, peaks=peaks.peaks("TPU v5 lite"),
+        chips=1, horizons=[types.SimpleNamespace(
+            shape={"sessions": 65536, "steps": 56})],
+        window=types.SimpleNamespace(units=16384 * 40), target=cell.target,
+        target_params=None, log=lambda s: None)
+    got = {m: spec.load_module(BENCH / "metrics" / f"{m}.py").read(ctx)
+           for m in PINNED[name]}
+    assert got == PINNED[name]
