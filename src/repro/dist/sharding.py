@@ -227,9 +227,8 @@ def put(x, axes):
     logical axis names (with the same divisibility/absent-axis fallbacks
     as :func:`constrain`).
 
-    Identity when no ruleset is active or the mesh is a single device —
-    the serving path's input placement degrades to plain host arrays on
-    CPU tests. Unlike ``constrain`` this runs *outside* jit: it commits
+    Identity when no ruleset is active or the mesh is a single device.
+    Unlike ``constrain`` this runs *outside* jit: it commits
     the array to the mesh so a jitted program with unspecified
     in_shardings picks the distributed layout up from its arguments.
     """

@@ -9,7 +9,9 @@ production-mesh SPMD program:
     over the mesh's ``data`` axis (and ``pod`` when present) through the
     ``batch`` rule of ``repro.dist.sharding`` — no new mechanism, the
     estimator's ``constrain`` annotations resolve against whatever mesh
-    is active;
+    is active. Host inputs go straight from host memory to that sharding
+    (``telemetry.ingest``'s ``put`` with axes), each device receiving
+    only its own shard; nothing is staged whole on the default device;
   * estimator weights stay replicated (their template axes are all
     ``None``), so per-period serving is pure data parallelism: zero
     cross-chip collectives in the forward, UE capacity scales linearly
@@ -159,9 +161,11 @@ def sharded_fleet_estimate(ecfg: EstimatorConfig, params, wins: np.ndarray,
 
     ``wins``: (N, T, WINDOW, 15) normalized KPM windows; ``iq``:
     (N, T, 2, n_sc, 14) spectrograms; ``alloc``: (N,) PRB ratios. Weights
-    are replicated onto the mesh once; each period's slice is committed
-    with the ``batch`` sharding (``dist.sharding.put``) and run through
-    the cached per-period program.
+    are replicated onto the mesh once; each period's host slice goes
+    straight to the ``batch`` sharding (``telemetry.ingest``'s ``put``
+    with axes: one shard to each device, over its own host link, never
+    staged whole on the default device) and runs through the cached
+    per-period program.
 
     ``window``: the fused-featurize form — ``wins`` is then the
     (N, T + WINDOW, 15) *normalized trace* and period ``t``'s batch is the
@@ -179,16 +183,16 @@ def sharded_fleet_estimate(ecfg: EstimatorConfig, params, wins: np.ndarray,
     params_r = replicate_params(serving, params)
     with sh.use_rules(serving.mesh, serving.rule_overrides()):
         with telmod.stage("estimator_fwd"), telmod.ingest() as put:
-            alloc_d = sh.put(put(alloc, jnp.float32), ("batch",))
+            alloc_d = put(alloc, jnp.float32, ("batch",))
         est = np.empty((n, t_steps))
         for t in range(t_steps):
             with telmod.stage("estimator_fwd"):
                 with telmod.ingest() as put:
                     win_t = (wins[:, t] if window is None
                              else wins[:, t:t + window])
-                    kpms_t = sh.put(put(win_t), ("batch", None, None))
-                    iq_t = sh.put(put(iq[:, t], jnp.float32),
-                                  ("batch", None, None, None))
+                    kpms_t = put(win_t, axes=("batch", None, None))
+                    iq_t = put(iq[:, t], jnp.float32,
+                               ("batch", None, None, None))
                 est[:, t] = np.clip(np.asarray(fn(params_r, kpms_t, iq_t,
                                                   alloc_d)),
                                     tp_clip[0], tp_clip[1])
@@ -246,8 +250,8 @@ def sharded_ssm_estimate(c: SSMConfig, params, feats: np.ndarray,
         for col in range(off + n_periods):
             with telmod.stage("estimator_fwd"):
                 with telmod.ingest() as put:
-                    feats_t = sh.put(put(feats[:, col], jnp.float32),
-                                     ("batch", None))
+                    feats_t = put(feats[:, col], jnp.float32,
+                                  ("batch", None))
                 state, fc = fn(params_r, state, feats_t)
                 if col >= off:
                     est[:, col - off] = np.clip(

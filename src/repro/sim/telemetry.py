@@ -44,12 +44,15 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import time
 from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.dist import sharding as sh
 
 F32 = jnp.float32
 I32 = jnp.int32
@@ -599,28 +602,51 @@ def stage(name: str, **stats):
 
 
 class _Put:
-    """``jnp.asarray`` that counts the bytes of what it made."""
+    """``jnp.asarray`` that counts the bytes of what it made and the most
+    devices one copy was split over.
+
+    ``axes`` (logical axis names, ``repro.dist.sharding``) under an
+    active ruleset whose mesh has more than one device: the host array,
+    cast by NumPy, goes straight to that sharding, so JAX cuts it and
+    sends each device its own shard; it is never committed whole to the
+    default device. Otherwise (no axes, no ruleset, one device) it is
+    ``jnp.asarray``."""
 
     def __init__(self):
         self.nbytes = 0
+        self.devices = 0
 
-    def __call__(self, x, dtype=None):
-        out = jnp.asarray(x, dtype)
-        self.nbytes += out.nbytes
+    def __call__(self, x, dtype=None, axes=None):
+        rs = None if axes is None else sh.active()
+        if rs is None or rs.mesh.size <= 1:
+            out = jnp.asarray(x, dtype)
+            split = 1
+        else:
+            host = np.asarray(x, dtype)
+            host = host.astype(jax.dtypes.canonicalize_dtype(host.dtype),
+                               copy=False)
+            sharding = rs.sharding(axes, host.shape)
+            out = jax.device_put(host, sharding)
+            split = math.prod(d // s for d, s in zip(
+                host.shape, sharding.shard_shape(host.shape)) if s)
+        self.nbytes += out.nbytes  # the global size, however split
+        self.devices = max(self.devices, split)
         return out
 
 
 @contextlib.contextmanager
 def ingest():
     """One stage's host-to-device copies as one ``ingest`` span. Yields
-    ``put`` (``jnp.asarray`` on host arrays); when the span closes, its
-    ``bytes`` stat is the ``nbytes`` of every array ``put`` made. Build
-    the host array inside the span too, so the cast and reshape that feed
-    a copy count as ingest."""
+    ``put`` (``jnp.asarray`` on host arrays, or a sharded copy given
+    ``axes``); when the span closes, its ``bytes`` stat is the ``nbytes``
+    of every array ``put`` made and its ``devices`` stat the most devices
+    one copy was split over (1 on one device, 0 with no copy). Build the
+    host array inside the span too, so the cast and reshape that feed a
+    copy count as ingest."""
     put = _Put()
     with stage("ingest") as span:
         yield put
-        span.set_metadata(bytes=put.nbytes)
+        span.set_metadata(bytes=put.nbytes, devices=put.devices)
 
 
 @contextlib.contextmanager

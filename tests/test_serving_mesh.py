@@ -8,6 +8,8 @@ sharded over the mesh's data axis, not silently replicated. Plus the EP
 mesh variant: the reserved ``experts`` logical axis finally resolves to
 a physical ``expert`` axis.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,11 +17,14 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.channel import scenarios as sc
+from repro.core.pso import TP_CLIP_MBPS
 from repro.dist import sharding as sh
 from repro.estimator.model import EstimatorConfig, init_estimator
 from repro.launch.mesh import make_host_mesh
 from repro.sim import estimate_fleet, make_serving_mesh
-from repro.sim.serving import ServingMesh, serving_program
+from repro.sim import serving as serving_mod
+from repro.sim import telemetry as tel
+from repro.sim.serving import ServingMesh, replicate_params, serving_program
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs >= 8 (virtual) devices")
@@ -123,6 +128,131 @@ def test_put_commits_batch_sharding():
     with sh.use_rules(serving.mesh):
         y = sh.put(x, ("batch", None))
     assert y.sharding.spec == P("data", None)
+
+
+# --------------------------------------------------------- input placement
+def _two_step_estimate(e, params, ep, serving):
+    """The mesh path as it placed inputs before: each host array committed
+    whole to the default device (``jnp.asarray``), then resharded onto
+    the ``batch`` sharding (``dist.sharding.put``)."""
+    fn = serving_program(e, serving)
+    wins = ep.kpm_windows(normalize=True).astype(np.float32)
+    params_r = replicate_params(serving, params)
+    est = np.empty((ep.n_ues, ep.n_steps))
+    with sh.use_rules(serving.mesh, serving.rule_overrides()):
+        alloc_d = sh.put(jnp.asarray(ep.alloc_ratio.astype(np.float32),
+                                     jnp.float32), ("batch",))
+        for t in range(ep.n_steps):
+            kpms_t = sh.put(jnp.asarray(wins[:, t]), ("batch", None, None))
+            iq_t = sh.put(jnp.asarray(ep.iq[:, t], jnp.float32),
+                          ("batch", None, None, None))
+            est[:, t] = np.asarray(fn(params_r, kpms_t, iq_t, alloc_d))
+    return np.clip(est, *TP_CLIP_MBPS)
+
+
+def _recorded_estimate(monkeypatch, e, params, ep, serving):
+    """estimate_fleet on the mesh, with the arguments handed to
+    ``fwd_sharded`` and every host array ``jnp.asarray`` or
+    ``jax.device_put`` was given on the way."""
+    real_fn = serving_program(e, serving)
+    args, staged, put = [], [], []
+
+    def fwd(p, *xs):
+        args.append(xs)
+        return real_fn(p, *xs)
+
+    real_asarray, real_put = jnp.asarray, jax.device_put
+
+    def asarray(x, *a, **k):
+        if isinstance(x, np.ndarray):
+            staged.append(x.shape)
+        return real_asarray(x, *a, **k)
+
+    def device_put(x, *a, **k):
+        if isinstance(x, np.ndarray):
+            put.append(x.shape)
+        return real_put(x, *a, **k)
+
+    monkeypatch.setattr(serving_mod, "serving_program", lambda *a: fwd)
+    monkeypatch.setattr(jnp, "asarray", asarray)
+    monkeypatch.setattr(jax, "device_put", device_put)
+    est = estimate_fleet(ep, (e, params), serving=serving)
+    monkeypatch.undo()
+    return est, args, staged, put
+
+
+@multi_device
+def test_host_iq_never_staged_whole_on_one_device(monkeypatch):
+    """On a 4x1 mesh each period's host IQ (and KPM window, and the alloc
+    ratio) goes to ``jax.device_put`` with its batch sharding; none is
+    committed whole to one device by ``jnp.asarray`` first."""
+    e, params = tiny_estimator()
+    ep = episode(8)
+    serving = make_serving_mesh("4x1")
+    _, args, staged, put = _recorded_estimate(monkeypatch, e, params, ep,
+                                              serving)
+    assert staged == []
+    iq_shape = (8, 2, N_SC_TEST, ep.iq.shape[-1])
+    assert put.count(iq_shape) == ep.n_steps
+    assert put.count((8,)) == 1  # the alloc ratio, once
+    assert len(args) == ep.n_steps
+
+
+@multi_device
+def test_fwd_sharded_args_carry_batch_sharding(monkeypatch):
+    """Every array handed to ``fwd_sharded`` has the ``batch``
+    NamedSharding on the 4-way data axis, N/4 rows per addressable
+    shard."""
+    e, params = tiny_estimator()
+    ep = episode(8)
+    serving = make_serving_mesh("4x1")
+    _, args, _, _ = _recorded_estimate(monkeypatch, e, params, ep, serving)
+    for xs in args:
+        for x in xs:
+            assert isinstance(x.sharding, jax.sharding.NamedSharding)
+            assert x.sharding.mesh == serving.mesh
+            assert x.sharding.spec == P("data", *(None,) * (x.ndim - 1))
+            shards = x.addressable_shards
+            assert len(shards) == 4
+            assert all(s.data.shape[0] == 8 // 4 for s in shards)
+
+
+@pytest.mark.parametrize("spec", [
+    pytest.param("4x1", marks=multi_device), "1x1"])
+def test_direct_placement_matches_two_step(spec, monkeypatch):
+    """The estimates equal the old two-step placement's bit for bit; on a
+    one-device mesh ``fwd_sharded`` still gets device arrays, never bare
+    NumPy."""
+    e, params = tiny_estimator()
+    ep = episode(8)
+    serving = make_serving_mesh(spec)
+    est, args, _, _ = _recorded_estimate(monkeypatch, e, params, ep,
+                                         serving)
+    np.testing.assert_array_equal(
+        est, _two_step_estimate(e, params, ep, serving))
+    assert all(isinstance(x, jax.Array) for xs in args for x in xs)
+
+
+@pytest.mark.parametrize("spec", [
+    pytest.param("4x1", marks=multi_device), "1x1", None])
+def test_ingest_put_with_axes(spec):
+    """``put`` with axes: under a multi-device ruleset the host array is
+    split over the batch axis (``devices`` counts the shards, ``nbytes``
+    the global size); on one device or with no ruleset it is
+    ``jnp.asarray``."""
+    x = np.arange(8 * 3, dtype=np.float64).reshape(8, 3)
+    with contextlib.ExitStack() as stack:
+        if spec is not None:
+            stack.enter_context(sh.use_rules(make_serving_mesh(spec).mesh))
+        with tel.ingest() as put:
+            y = put(x, axes=("batch", None))
+    n = 4 if spec == "4x1" else 1
+    assert isinstance(y, jax.Array) and y.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(y), x.astype(np.float32))
+    assert put.nbytes == x.size * 4 and put.devices == n
+    assert len({s.device for s in y.addressable_shards}) == n
+    if n == 1:
+        assert y.sharding.is_equivalent_to(jnp.asarray(x).sharding, 2)
 
 
 # ----------------------------------------------------------------- EP mesh

@@ -17,7 +17,7 @@ from repro.channel.scenarios import WINDOW
 from repro.core.controller import ControllerConfig
 from repro.core.pso import LookupTable
 from repro.estimator.model import EstimatorConfig, init_estimator
-from repro.estimator.ssm import SSMConfig, init_ssm
+from repro.estimator.ssm import SSMConfig, episode_features, init_ssm
 from repro.models.vgg import FULL, vgg_split_profile
 from repro.sim import (SchedulerConfig, estimate_fleet, make_serving_mesh,
                        simulate_fleet)
@@ -191,7 +191,8 @@ def test_results_identical_under_profiler(kind, prof, table, tmp_path):
 def test_ingest_counts_what_it_copies(tmp_path):
     x = np.arange(12, dtype=np.float64).reshape(3, 4)
     _, tree = _traced(tmp_path, lambda: _ingest_two(x))
-    assert tree == [("ingest", CALL, {"bytes": 12 * 4 + 3 * 4})]
+    assert tree == [("ingest", CALL, {"bytes": 12 * 4 + 3 * 4,
+                                      "devices": 1})]
 
 
 def _ingest_two(x):
@@ -221,3 +222,25 @@ def test_mesh_path_spans_and_names(est, tmp_path):
     assert {(n, p) for n, p, _ in tree if p != CALL} \
         == {("ingest", "estimator_fwd")}
     assert all(st["bytes"] > 0 for n, _, st in tree if n == "ingest")
+    # each copy is split over every chip of the mesh, and counts the
+    # global bytes the shapes give
+    spans = [st for n, _, st in tree if n == "ingest"]
+    assert [st["devices"] for st in spans] == [mesh.n_chips] * len(spans)
+    n, t = ep.n_ues, ep.n_steps
+    if est == "lstm":  # the alloc ratio once, then kpms and IQ per period
+        want = [n * F32] + [n * (WINDOW * 15 + 2 * N_SC * 14) * F32] * t
+    else:  # one report column per step, warmup included
+        cols = ep.kpms.shape[1] - 1
+        width = episode_features(ep.kpms[:1], ep.alloc_ratio[:1]).shape[-1]
+        want = [n * width * F32] * cols
+    assert [st["bytes"] for st in spans] == want
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_one_chip_ingest_reads_one_device(kind, prof, table, tmp_path):
+    """Off the mesh every ``ingest`` span carries ``devices`` = 1: each
+    copy goes whole to the one device."""
+    call, _, _ = _setup(kind)
+    _, tree = _traced(tmp_path, lambda: call(prof, table))
+    spans = [st for n, _, st in tree if n == "ingest"]
+    assert spans and all(st["devices"] == 1 for st in spans)
