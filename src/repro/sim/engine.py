@@ -73,8 +73,8 @@ class FleetResult:
     lifecycle: Optional[object] = None  # sim.pool.LifecycleStats when the
     # run churned (admissions, departures, admission latency); else None
     telemetry: Optional[object] = None  # sim.telemetry.TelemetryRecord when
-    # the run was passed ``telemetry=TelemetryConfig(...)``; None (and the
-    # traced programs untouched) on the default path
+    # the run was passed ``telemetry=TelemetryConfig(...)``; None (and
+    # nothing of the metric plane traced) on the default path
 
     @property
     def n_ues(self) -> int:
@@ -125,116 +125,102 @@ def split_metrics(profile: SplitProfile, splits: np.ndarray,
 def _sweep_fn(ewma_alpha: float, hysteresis_steps: int, fallback_split: int,
               sched: Optional[SchedulerConfig] = None, n_cells: int = 1,
               telem: Optional[TelemetryConfig] = None):
-    """Compiled fleet sweep, cached per controller (+ scheduler) config
-    (jit's own cache then handles distinct fleet shapes).
+    """The compiled controller scan, cached per controller, scheduler and
+    telemetry config (jit's own cache then handles distinct fleet shapes).
 
-    Without a scheduler this is the PR-2 program, untouched: controllers
-    consume the estimates as-is. With one, the gNB scheduler runs *inside*
-    the scan so allocation, estimation and splitting co-evolve: each
-    period the scheduler divides every cell's PRB budget over its attached
-    UEs (PF state carried across periods), and each controller sees its
-    estimate scaled by the share it was actually granted.
+    ``sweep(tab, warm, est, rate, cells, dconst, dbytes, ts0)`` returns
+    ``(splits, shares, ts, rows)``. One scan body serves every config:
+    which parts it holds is decided by Python branches at trace time, and
+    the arguments and outputs a config does not use are None (no leaves),
+    so ``sched=None, telem=None`` traces ``(tab, warm, est) -> splits``
+    with nothing of the scheduler or the metric plane in it.
 
-    ``telem`` (default None) selects the telemetry variant: the same scan
-    additionally carries a ``TelemetryState``, folding each period's
-    splits / error / delay / shares into it via ``telemetry_step`` and
-    (on the scheduled arm) logging cell-index changes as handover events.
-    ``telem=None`` returns the exact prior programs — the telemetry
-    variants are *separate* cache entries, never a branch inside the
-    default trace."""
+    Without a scheduler the controllers consume the estimates as-is. With
+    one, the gNB scheduler runs *inside* the scan so allocation,
+    estimation and splitting co-evolve: each period it divides every
+    cell's PRB budget over its attached UEs (PF state carried across
+    periods) from ``rate``, the full-grant rate, and each controller sees
+    its estimate scaled by the share it was granted. With ``telem`` the
+    carry also holds a ``TelemetryState`` that ``telemetry_step`` folds
+    each period's splits, error, delay and shares into against ``rate``
+    as the truth; on the scheduled arm the carry keeps the previous
+    period's cell index and logs its changes as handover events."""
     cfg = ControllerConfig(ewma_alpha, hysteresis_steps, fallback_split)
     step = functools.partial(controller_step, cfg=cfg)
 
-    if sched is None:
-        if telem is None:
-            @jax.jit
-            def sweep(tab, warm, est):
-                init = controller_init(warm, batch_shape=tab.shape[:1])
-
-                def body(state, tp_t):
-                    return jax.vmap(step)(tab, state, tp_t)
-
-                _, splits = lax.scan(body, init, est.T)
-                return splits.T
-
-            return sweep
-
-        @jax.jit
-        def sweep_telem(tab, warm, est, true, dconst, dbytes, ts0):
-            init = (controller_init(warm, batch_shape=tab.shape[:1]), ts0)
-            ones = jnp.ones(tab.shape[:1], jnp.float32)
-            live = jnp.ones(tab.shape[:1], bool)
-
-            def body(carry, xs):
-                ctl, ts = carry
-                est_t, true_t, t = xs
-                with jax.named_scope("controller_step"):
-                    ctl, split = jax.vmap(step)(tab, ctl, est_t)
-                with jax.named_scope("telemetry_step"):
-                    ts, row = telmod.telemetry_step(
-                        telem, ts, period=t, split=split, est_tp=est_t,
-                        true_tp=true_t, share=ones, active=live,
-                        dconst=dconst, dbytes=dbytes)
-                return (ctl, ts), (split, row)
-
-            (_, ts), (splits, rows) = lax.scan(
-                body, init,
-                (est.T, true.T, jnp.arange(est.shape[1], dtype=jnp.int32)))
-            return splits.T, ts, rows
-
-        return sweep_telem
-
-    if telem is None:
-        @jax.jit
-        def sweep_scheduled(tab, warm, est, rate, cells):
-            init = (controller_init(warm, batch_shape=tab.shape[:1]),
-                    scheduler_init(tab.shape[0]))
-
-            def body(carry, xs):
-                ctl, ss = carry
-                est_t, rate_t, cell_t = xs
-                ss, share = scheduler_step(sched, n_cells, ss, cell_t, rate_t)
-                ctl, split = jax.vmap(step)(tab, ctl, est_t * share)
-                return (ctl, ss), (split, share)
-
-            _, (splits, shares) = lax.scan(body, init,
-                                           (est.T, rate.T, cells.T))
-            return splits.T, shares.T
-
-        return sweep_scheduled
+    def _t(x):
+        return None if x is None else x.T
 
     @jax.jit
-    def sweep_scheduled_telem(tab, warm, est, rate, cells, dconst, dbytes,
-                              ts0):
-        init = (controller_init(warm, batch_shape=tab.shape[:1]),
-                scheduler_init(tab.shape[0]), ts0, cells[:, 0])
+    def sweep(tab, warm, est, rate=None, cells=None, dconst=None,
+              dbytes=None, ts0=None):
+        n = tab.shape[0]
+        init = (controller_init(warm, batch_shape=(n,)),
+                None if sched is None else scheduler_init(n), ts0,
+                None if sched is None or telem is None else cells[:, 0])
+        periods = (None if telem is None
+                   else jnp.arange(est.shape[1], dtype=jnp.int32))
 
         def body(carry, xs):
             ctl, ss, ts, prev_cell = carry
             est_t, rate_t, cell_t, t = xs
-            with jax.named_scope("scheduler_step"):
-                ss, share = scheduler_step(sched, n_cells, ss, cell_t, rate_t)
+            share = None
+            if sched is not None:
+                with jax.named_scope("scheduler_step"):
+                    ss, share = scheduler_step(sched, n_cells, ss, cell_t,
+                                               rate_t)
             with jax.named_scope("controller_step"):
-                ctl, split = jax.vmap(step)(tab, ctl, est_t * share)
-            with jax.named_scope("telemetry_step"):
-                # what split_metrics sees: PRB-scaled, floored throughput
-                eff = jnp.maximum(rate_t * jnp.clip(share, 0.0, 1.0),
-                                  tpmod.PRB_FLOOR_MBPS)
-                hand = (cell_t != prev_cell).sum(dtype=jnp.int32)
-                ts, row = telmod.telemetry_step(
-                    telem, ts, period=t, split=split, est_tp=est_t,
-                    true_tp=rate_t, eff_tp=eff, share=share,
-                    active=jnp.ones(tab.shape[:1], bool), dconst=dconst,
-                    dbytes=dbytes, n_handover=hand)
-            return (ctl, ss, ts, cell_t), (split, share, row)
+                ctl, split = jax.vmap(step)(
+                    tab, ctl, est_t if share is None else est_t * share)
+            row = None
+            if telem is not None:
+                with jax.named_scope("telemetry_step"):
+                    if sched is None:
+                        extra = {"share": jnp.ones((n,), jnp.float32)}
+                    else:
+                        # what split_metrics sees: PRB-scaled, floored
+                        eff = jnp.maximum(rate_t * jnp.clip(share, 0.0, 1.0),
+                                          tpmod.PRB_FLOOR_MBPS)
+                        hand = (cell_t != prev_cell).sum(dtype=jnp.int32)
+                        extra = {"share": share, "eff_tp": eff,
+                                 "n_handover": hand}
+                        prev_cell = cell_t
+                    ts, row = telmod.telemetry_step(
+                        telem, ts, period=t, split=split, est_tp=est_t,
+                        true_tp=rate_t, active=jnp.ones((n,), bool),
+                        dconst=dconst, dbytes=dbytes, **extra)
+            return (ctl, ss, ts, prev_cell), (split, share, row)
 
         (_, _, ts, _), (splits, shares, rows) = lax.scan(
-            body, init,
-            (est.T, rate.T, cells.T,
-             jnp.arange(est.shape[1], dtype=jnp.int32)))
-        return splits.T, shares.T, ts, rows
+            body, init, (est.T, _t(rate), _t(cells), periods))
+        return splits.T, _t(shares), ts, rows
 
-    return sweep_scheduled_telem
+    return sweep
+
+
+def _run_sweep(tables, est_tp, cfg: ControllerConfig, warm_split,
+               true_tp=None, *, sched: Optional[SchedulerConfig] = None,
+               n_cells: int = 1, cell_idx=None,
+               telem: Optional[TelemetryConfig] = None, dconst=None,
+               dbytes=None, ts0=None):
+    """One controller scan in the ``controller`` span: copy in what the
+    config reads (``true_tp`` only with a scheduler or telemetry,
+    ``cell_idx`` only with a scheduler), dispatch, read the splits and
+    shares back. Returns ``(splits, shares, ts, rows)``; ``shares`` is
+    None without a scheduler, ``ts`` and ``rows`` (left on the device)
+    without telemetry."""
+    sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
+                      cfg.fallback_split, sched, int(n_cells), telem)
+    with telmod.stage("controller"):
+        with telmod.ingest() as put:
+            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
+                    put(est_tp, jnp.float32),
+                    None if sched is None and telem is None
+                    else put(true_tp, jnp.float32),
+                    None if sched is None else put(cell_idx, jnp.int32))
+        splits, shares, ts, rows = sweep(*args, dconst, dbytes, ts0)
+        return (np.asarray(splits),
+                None if shares is None else np.asarray(shares), ts, rows)
 
 
 def run_controllers(tables: np.ndarray, est_tp: np.ndarray,
@@ -243,13 +229,7 @@ def run_controllers(tables: np.ndarray, est_tp: np.ndarray,
 
     ``tables``: (N, tp_max+1) stacked lookup rows (``StackedLookupTable
     .tables``); ``warm_split``: scalar or (N,) deployed-split warm start."""
-    sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
-                      cfg.fallback_split)
-    with telmod.stage("controller"):
-        with telmod.ingest() as put:
-            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
-                    put(est_tp, jnp.float32))
-        return np.asarray(sweep(*args))
+    return _run_sweep(tables, est_tp, cfg, warm_split)[0]
 
 
 def run_scheduled(tables: np.ndarray, est_tp: np.ndarray,
@@ -265,52 +245,9 @@ def run_scheduled(tables: np.ndarray, est_tp: np.ndarray,
     (inter-cell handover = the index changing mid-episode);
     ``rate_mbps``: (N, T) the gNB's CQI view (full-grant achievable rate,
     Mbps) driving the scheduler. This is the ``sched is not None`` arm of
-    ``simulate_fleet``; with ``sched=None`` the engine takes
-    ``run_controllers`` instead, whose program is bit-identical to PR 2.
-    """
-    sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
-                      cfg.fallback_split, sched, int(n_cells))
-    with telmod.stage("controller"):
-        with telmod.ingest() as put:
-            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
-                    put(est_tp, jnp.float32), put(rate_mbps, jnp.float32),
-                    put(cell_idx, jnp.int32))
-        splits, shares = sweep(*args)
-        return np.asarray(splits), np.asarray(shares)
-
-
-def _run_controllers_telem(tables, est_tp, true_tp, cfg: ControllerConfig,
-                           warm_split, tcfg: TelemetryConfig, dconst, dbytes,
-                           ts0):
-    """``run_controllers`` with the metric plane carried through the scan:
-    also returns the final ``TelemetryState`` and the stacked per-period
-    rows. The public entry point stays untouched — the telemetry variant
-    is a distinct compiled program."""
-    sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
-                      cfg.fallback_split, telem=tcfg)
-    with telmod.stage("controller"):
-        with telmod.ingest() as put:
-            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
-                    put(est_tp, jnp.float32), put(true_tp, jnp.float32))
-        splits, ts, rows = sweep(*args, dconst, dbytes, ts0)
-        return np.asarray(splits), ts, rows
-
-
-def _run_scheduled_telem(tables, est_tp, cfg: ControllerConfig, warm_split,
-                         sched: SchedulerConfig, n_cells: int, cell_idx,
-                         rate_mbps, tcfg: TelemetryConfig, dconst, dbytes,
-                         ts0):
-    """``run_scheduled`` with the metric plane (handover events included)
-    carried through the scan."""
-    sweep = _sweep_fn(cfg.ewma_alpha, cfg.hysteresis_steps,
-                      cfg.fallback_split, sched, int(n_cells), telem=tcfg)
-    with telmod.stage("controller"):
-        with telmod.ingest() as put:
-            args = (put(tables, jnp.int32), put(warm_split, jnp.int32),
-                    put(est_tp, jnp.float32), put(rate_mbps, jnp.float32),
-                    put(cell_idx, jnp.int32))
-        splits, shares, ts, rows = sweep(*args, dconst, dbytes, ts0)
-        return np.asarray(splits), np.asarray(shares), ts, rows
+    ``simulate_fleet``."""
+    return _run_sweep(tables, est_tp, cfg, warm_split, rate_mbps,
+                      sched=sched, n_cells=n_cells, cell_idx=cell_idx)[:2]
 
 
 def emit_period_samples(episode: EpisodeBatch, t: int,
@@ -525,6 +462,18 @@ def _estimate_fleet_ssm(episode: EpisodeBatch, ecfg: SSMConfig, params,
     return np.clip(est, tp_clip[0], tp_clip[1])
 
 
+def _host_telemetry(telemetry: Optional[TelemetryConfig],
+                    profile: SplitProfile, ue: DeviceProfile,
+                    server: DeviceProfile):
+    """(``HostTelemetry``, per-split delay constants, boundary bytes) for
+    a run with ``telemetry``; three Nones without it."""
+    if telemetry is None:
+        return None, None, None
+    dconst = np.asarray(profile.d_ue(ue)) + np.asarray(profile.d_ser(server))
+    return (telmod.HostTelemetry(telemetry), jnp.asarray(dconst, jnp.float32),
+            jnp.asarray(profile.data_bytes, jnp.float32))
+
+
 def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
                    cfg: ControllerConfig, *, warm_split=None, estimator=None,
                    serving: Optional[ServingMesh] = None,
@@ -583,24 +532,24 @@ def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
     admission/departure stats; ``cell_idx`` is interpreted as a (N,)
     per-session static cell attach.
 
-    Equivalence guarantee: with ``sched=None`` the scheduler hook is a
-    strict no-op — the traced program is the PR-2 engine unchanged, split
-    decisions are bit-identical and metrics float-identical to it (pinned
-    by ``tests/test_sim_cells.py`` and the ``cells/noop_equivalence``
-    benchmark record). Sharded serving does not weaken this: it changes
-    where the estimator forward runs, not the controller scan. Likewise
-    ``online=None`` (the default) never touches ``repro.sim.online`` —
-    the estimates, splits and metrics are bit-identical to the PR 4
-    engine (pinned by ``tests/test_sim_online.py``) — and ``churn=None``
-    (the default) never touches ``repro.sim.pool``: the batch-synchronous
-    path below is the PR 5 program unchanged (pinned by
+    Equivalence guarantee: with ``sched=None`` the scheduler branch of
+    the controller scan is not traced, so the controllers consume the
+    estimates as-is (pinned by ``tests/test_sim_cells.py`` and the
+    ``cells/noop_equivalence`` benchmark record). Sharded serving does not
+    weaken this: it changes where the estimator forward runs, not the
+    controller scan. Likewise ``online=None`` (the default) never touches
+    ``repro.sim.online`` — the estimates are the frozen estimator's
+    (pinned by ``tests/test_sim_online.py``) — and ``churn=None`` (the
+    default) never touches ``repro.sim.pool``: the batch-synchronous path
+    below runs (the degenerate full pool is pinned equal to it by
     ``tests/test_sim_pool.py``).
 
     ``quant`` / ``fused`` (defaults None / False): the int8 serving and
     fused-featurize switches, forwarded to ``estimate_fleet`` (and the
     pool/online loops). They change how the per-period estimates are
-    computed, never the controller scan; with the defaults the program is
-    bit-identical to the PR 6 engine (pinned by
+    computed, never the controller scan; the defaults spelled out run
+    the default program, and the fused path's estimates match it to
+    float tolerance with the same splits (both pinned by
     ``tests/test_sim_fused.py``). ``quant`` requires a frozen estimator
     (the online trainer adapts fp32 weights).
 
@@ -610,9 +559,9 @@ def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
     inside the controller scan (and the online loop logs drift/burst/swap
     events into the same ring), decoded once at run end into
     ``FleetResult.telemetry`` (a ``TelemetryRecord``). ``telemetry=None``
-    never builds any of it: the traced programs, splits and metrics are
-    bit-identical to the prior engine (pinned by
-    ``tests/test_sim_telemetry.py``). ``TelemetryConfig(trace_dir=...)``
+    never builds any of it (the scan's telemetry branch is not traced),
+    and telemetry on leaves the splits and metrics bit-identical (pinned
+    by ``tests/test_sim_telemetry.py``). ``TelemetryConfig(trace_dir=...)``
     additionally wraps the run in a ``jax.profiler.trace`` capture.
     """
     check_quant(quant)
@@ -635,14 +584,8 @@ def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
               else np.broadcast_to(table.table,
                                    (episode.n_ues, len(table.table))))
     true_tp = np.asarray(episode.tp_mbps, float)
-    tel = dconst = dbytes = None
-    if telemetry is not None:
-        tel = telmod.HostTelemetry(telemetry)
-        dconst = jnp.asarray(np.asarray(profile.d_ue(ue))
-                             + np.asarray(profile.d_ser(server)), jnp.float32)
-        dbytes = jnp.asarray(profile.data_bytes, jnp.float32)
+    tel, dconst, dbytes = _host_telemetry(telemetry, profile, ue, server)
     online_stats = None
-    rows = None
     with telmod.trace_capture(telemetry.trace_dir
                               if telemetry is not None else None):
         if online is not None:
@@ -659,25 +602,17 @@ def simulate_fleet(episode: EpisodeBatch, table, profile: SplitProfile,
         if warm_split is None:
             warm_split = (cfg.fallback_split if fixed_split is None
                           else fixed_split)
-        if sched is None:
-            shares, eff_tp = None, true_tp
-            if telemetry is None:
-                splits = run_controllers(tables, est_tp, cfg, warm_split)
-            else:
-                splits, tel.ts, rows = _run_controllers_telem(
-                    tables, est_tp, true_tp, cfg, warm_split, telemetry,
-                    dconst, dbytes, tel.ts)
-        else:
-            if cell_idx is None:
-                raise ValueError("a scheduler needs a (N, T) cell_idx")
-            if telemetry is None:
-                splits, shares = run_scheduled(tables, est_tp, cfg,
-                                               warm_split, sched, n_cells,
-                                               cell_idx, true_tp)
-            else:
-                splits, shares, tel.ts, rows = _run_scheduled_telem(
-                    tables, est_tp, cfg, warm_split, sched, n_cells,
-                    cell_idx, true_tp, telemetry, dconst, dbytes, tel.ts)
+        if sched is not None and cell_idx is None:
+            raise ValueError("a scheduler needs a (N, T) cell_idx")
+        splits, shares, ts, rows = _run_sweep(
+            tables, est_tp, cfg, warm_split, true_tp, sched=sched,
+            n_cells=n_cells, cell_idx=cell_idx, telem=telemetry,
+            dconst=dconst, dbytes=dbytes,
+            ts0=tel.ts if tel is not None else None)
+        eff_tp = true_tp
+        if tel is not None:
+            tel.ts = ts
+        if shares is not None:
             eff_tp = tpmod.prb_scaled_mbps(true_tp, shares)
             est_tp = est_tp * shares  # what the controllers consumed
     with telmod.stage("split_metrics"):

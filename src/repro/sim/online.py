@@ -27,9 +27,10 @@ throughput each report period emits):
     hit, no retrace) and checkpoint them via
     ``checkpoint.CheckpointManager``.
 
-``simulate_fleet(online=None)`` never enters this module: the engine's
-default path is bit-identical to the PR 4 program (pinned by
-``tests/test_sim_online.py``).
+The four online loops — batch or slot pool, windowed or recurrent —
+share that machinery through :class:`OnlineAdapter`.
+``simulate_fleet(online=None)`` never enters this module: the engine
+serves the frozen estimator (pinned by ``tests/test_sim_online.py``).
 """
 from __future__ import annotations
 
@@ -510,6 +511,131 @@ def online_step_program(ecfg: EstimatorConfig, opt: AdamW,
                    overrides=serving.rule_overrides())
 
 
+def _place(x, axes):
+    """``x`` as an f32 device array on the active serving mesh's sharding
+    for these logical axes (``dist.sharding.put``; a plain device array
+    with no mesh)."""
+    return sh.put(jnp.asarray(x, F32), axes)
+
+
+class OnlineAdapter:
+    """The adaptation half every online loop shares: the batch and pool
+    loops, windowed and recurrent, each bring their period's inputs, their
+    predict call on :attr:`params`, their RMSE and their ring add; this
+    owns the rest.
+
+    That is the optimizer state and its compiled step
+    (:func:`online_step_program`), the predict program for the
+    estimator's kind (``fwd`` / ``ssm_step``, or their ``sim.serving``
+    programs with the params replicated under a mesh), the replay ring,
+    the drift monitor, the minibatch ``rng`` and dropout ``key`` (both
+    seeded from ``ocfg.seed``), the optional ``CheckpointManager`` and the
+    per-period trace that :meth:`finish` returns as :class:`OnlineStats`.
+    Per period a loop calls :meth:`add` and then :meth:`observe`.
+
+    ``telemetry``: an optional ``telemetry.HostTelemetry``; the adapter
+    logs drift trigger/recovery, burst start/end and serving weight-swap
+    events into its device ring."""
+
+    def __init__(self, estimator, ocfg: OnlineConfig, t_steps: int, *,
+                 serving: Optional[ServingMesh] = None, telemetry=None):
+        ecfg, params = estimator
+        self.ocfg, self.serving, self.telemetry = ocfg, serving, telemetry
+        opt = AdamW(lr=ocfg.lr, weight_decay=ocfg.weight_decay,
+                    clip_norm=ocfg.clip_norm)
+        self.opt_state = opt.init(params)
+        self.step_fn = online_step_program(ecfg, opt, serving)
+        recurrent = isinstance(ecfg, SSMConfig)
+        if serving is not None:
+            self.predict = (ssm_serving_program if recurrent
+                            else serving_program)(ecfg, serving)
+            params = replicate_params(serving, params)
+        else:
+            self.predict = functools.partial(ssm_step if recurrent else fwd,
+                                             ecfg)
+        self.params = params
+        self.mgr = (CheckpointManager(ocfg.ckpt_dir, keep=ocfg.ckpt_keep)
+                    if ocfg.ckpt_dir else None)
+        self.buf = buffer_init(ocfg.capacity, ecfg, serving=serving,
+                               quant=ocfg.ring_quant)
+        self.dstate = drift_init()
+        self.rng = np.random.default_rng(ocfg.seed)
+        self.key = jax.random.PRNGKey(ocfg.seed)
+        self.rmse = np.zeros(t_steps)
+        self.adapted = np.zeros(t_steps, bool)
+        self.train_loss: list = []
+        self.ckpt_steps: list = []
+        self.total_steps = 0
+
+    def rules(self):
+        """The serving mesh's sharding rules as a context manager (a no-op
+        without a mesh); the loop runs its periods inside it."""
+        if self.serving is None:
+            return contextlib.nullcontext()
+        return sh.use_rules(self.serving.mesh, self.serving.rule_overrides())
+
+    def add(self, buffer_add_fn, *rows, **kw):
+        """Ring-ingest the period's rows with the loop's own
+        ``buffer_add`` / ``buffer_add_masked`` / ``buffer_add_ssm``."""
+        self.buf = buffer_add_fn(self.buf, *rows, **kw)
+
+    def observe(self, t: int, rmse: float) -> bool:
+        """Feed period ``t``'s estimator RMSE to the drift monitor and, when
+        it fires, run one adaptation burst: ``ocfg.steps`` AdamW steps on
+        ring minibatches, the weight swap under a mesh, the checkpoint.
+        Returns whether period ``t`` adapted (:attr:`params` changed)."""
+        o = self.ocfg
+        self.rmse[t] = rmse
+        fill = buffer_count(self.buf)
+        # below min_fill the monitor holds its streak instead of consuming
+        # the trigger: a drift detected before the buffer is ready fires
+        # on the first period it can be acted on
+        self.dstate, fired = drift_step(o.drift, self.dstate, rmse,
+                                        armed=fill >= o.min_fill)
+        if self.telemetry is not None:
+            self.telemetry.drift(t, bool(fired), rmse,
+                                 drift_threshold(o.drift, self.dstate),
+                                 n_triggers=int(self.dstate.n_triggers))
+        if not fired:
+            return False
+        data = buffer_data(self.buf)
+        burst = []
+        with telmod.stage("online_burst"):
+            for _ in range(o.steps):
+                idx = jnp.asarray(self.rng.integers(0, fill, o.batch), I32)
+                self.key, sub = jax.random.split(self.key)
+                self.params, self.opt_state, loss = self.step_fn(
+                    self.params, self.opt_state, data, idx, sub)
+                burst.append(float(loss))
+            if self.serving is not None:
+                # weight refresh: re-commit replicated so the next period's
+                # predict is a compiled-program cache hit
+                with telmod.stage("weight_swap"):
+                    self.params = replicate_params(self.serving, self.params)
+        self.total_steps += o.steps
+        self.train_loss.append(float(np.mean(burst)))
+        self.adapted[t] = True
+        if self.telemetry is not None:
+            self.telemetry.burst(t, o.steps, float(np.mean(burst)),
+                                 self.serving is not None)
+        if self.mgr is not None:
+            self.mgr.save(self.dstate.n_triggers, self.params)  # async
+            self.ckpt_steps.append(self.dstate.n_triggers)
+        return True
+
+    def finish(self) -> OnlineStats:
+        """Wait for the checkpoint writes; the run's :class:`OnlineStats`."""
+        if self.mgr is not None:
+            self.mgr.wait()
+        return OnlineStats(
+            rmse=self.rmse, adapted=self.adapted,
+            n_adaptations=int(self.adapted.sum()),
+            train_steps=self.total_steps, train_loss=self.train_loss,
+            buffer_fill=buffer_count(self.buf),
+            threshold_mbps=drift_threshold(self.ocfg.drift, self.dstate),
+            params=self.params, ckpt_steps=self.ckpt_steps)
+
+
 def online_estimate_fleet(episode, estimator, ocfg: OnlineConfig, *,
                           serving: Optional[ServingMesh] = None,
                           tp_clip=TP_CLIP_MBPS, fused: bool = False,
@@ -524,7 +650,8 @@ def online_estimate_fleet(episode, estimator, ocfg: OnlineConfig, *,
     (``engine.emit_period_samples``) and ring-ingest the (kpms, iq, alloc
     -> tp) rows; (3) feed the period RMSE to the drift monitor; (4) on a
     trigger, run ``ocfg.steps`` jitted AdamW steps on buffer minibatches,
-    swap the updated weights into the serving path, and checkpoint them.
+    swap the updated weights into the serving path, and checkpoint them
+    (steps 3-4 are :meth:`OnlineAdapter.observe`).
 
     The estimates returned are exactly what the controllers consume
     (clipped into ``tp_clip``); period t+1's estimate already reflects any
@@ -545,12 +672,11 @@ def online_estimate_fleet(episode, estimator, ocfg: OnlineConfig, *,
     """
     from repro.sim.engine import emit_period_samples
 
-    ecfg, params = estimator
-    if isinstance(ecfg, SSMConfig):
+    if isinstance(estimator[0], SSMConfig):
         # the recurrent loop: same drift monitor, same AdamW bursts, the
         # ring stores (pre-report state, report, label) events instead of
         # windows; ``fused`` is a no-op (nothing to featurize)
-        return _online_estimate_fleet_ssm(episode, ecfg, params, ocfg,
+        return _online_estimate_fleet_ssm(episode, estimator, ocfg,
                                           serving=serving, tp_clip=tp_clip,
                                           telemetry=telemetry)
     if episode.iq is None:
@@ -565,95 +691,26 @@ def online_estimate_fleet(episode, estimator, ocfg: OnlineConfig, *,
     else:
         wins = episode.kpm_windows(normalize=True).astype(np.float32)
         trace = None
-    opt = AdamW(lr=ocfg.lr, weight_decay=ocfg.weight_decay,
-                clip_norm=ocfg.clip_norm)
-    opt_state = opt.init(params)
-    step_fn = online_step_program(ecfg, opt, serving)
-    if serving is not None:
-        predict_fn = serving_program(ecfg, serving)
-        params = replicate_params(serving, params)
-        ctx = sh.use_rules(serving.mesh, serving.rule_overrides())
-    else:
-        predict_fn = functools.partial(fwd, ecfg)
-        ctx = contextlib.nullcontext()
-    mgr = (CheckpointManager(ocfg.ckpt_dir, keep=ocfg.ckpt_keep)
-           if ocfg.ckpt_dir else None)
-    buf = buffer_init(ocfg.capacity, ecfg, serving=serving,
-                      quant=ocfg.ring_quant)
-    dstate = drift_init()
-    rng = np.random.default_rng(ocfg.seed)
-    key = jax.random.PRNGKey(ocfg.seed)
+    ad = OnlineAdapter(estimator, ocfg, t_steps, serving=serving,
+                       telemetry=telemetry)
     est = np.empty((n, t_steps))
-    rmse = np.empty(t_steps)
-    adapted = np.zeros(t_steps, bool)
-    train_loss: list = []
-    ckpt_steps: list = []
-    total_steps = 0
-    with ctx:
-        def place(x, axes):
-            return sh.put(jnp.asarray(x, F32), axes)
-
-        alloc_d = place(episode.alloc_ratio, ("batch",))
+    with ad.rules():
+        alloc_d = _place(episode.alloc_ratio, ("batch",))
         for t in range(t_steps):
             s = emit_period_samples(episode, t, wins, trace=trace)
-            kpms_t = place(s["kpms"], ("batch", None, None))
-            iq_t = place(s["iq"], ("batch", None, None, None))
+            kpms_t = _place(s["kpms"], ("batch", None, None))
+            iq_t = _place(s["iq"], ("batch", None, None, None))
             with telmod.stage("estimator_fwd"):
                 est[:, t] = np.clip(
-                    np.asarray(predict_fn(params, kpms_t, iq_t, alloc_d)),
+                    np.asarray(ad.predict(ad.params, kpms_t, iq_t, alloc_d)),
                     tp_clip[0], tp_clip[1])
             tp_t = s["tp"]
-            rmse[t] = float(np.sqrt(np.mean((est[:, t] - tp_t) ** 2)))
-            buf = buffer_add(buf, kpms_t, iq_t, alloc_d,
-                             place(tp_t, ("batch",)))
-            fill = buffer_count(buf)
-            # below min_fill the monitor holds its streak instead of
-            # consuming the trigger: a drift detected before the buffer
-            # is ready fires on the first period it can be acted on
-            dstate, fired = drift_step(ocfg.drift, dstate, rmse[t],
-                                       armed=fill >= ocfg.min_fill)
-            if telemetry is not None:
-                telemetry.drift(t, bool(fired), rmse[t],
-                                drift_threshold(ocfg.drift, dstate),
-                                n_triggers=int(dstate.n_triggers))
-            if fired:
-                data = buffer_data(buf)
-                burst = []
-                with telmod.stage("online_burst"):
-                    for _ in range(ocfg.steps):
-                        idx = jnp.asarray(rng.integers(0, fill, ocfg.batch),
-                                          I32)
-                        key, sub = jax.random.split(key)
-                        params, opt_state, loss = step_fn(params, opt_state,
-                                                          data, idx, sub)
-                        burst.append(float(loss))
-                    if serving is not None:
-                        # weight refresh: re-commit replicated so the next
-                        # period's predict is a compiled-program cache hit
-                        with telmod.stage("weight_swap"):
-                            params = replicate_params(serving, params)
-                total_steps += ocfg.steps
-                train_loss.append(float(np.mean(burst)))
-                adapted[t] = True
-                if telemetry is not None:
-                    telemetry.burst(t, ocfg.steps, float(np.mean(burst)),
-                                    serving is not None)
-                if mgr is not None:
-                    mgr.save(dstate.n_triggers, params)  # async
-                    ckpt_steps.append(dstate.n_triggers)
-    if mgr is not None:
-        mgr.wait()
-    stats = OnlineStats(rmse=rmse, adapted=adapted,
-                        n_adaptations=int(adapted.sum()),
-                        train_steps=total_steps, train_loss=train_loss,
-                        buffer_fill=buffer_count(buf),
-                        threshold_mbps=drift_threshold(ocfg.drift, dstate),
-                        params=params, ckpt_steps=ckpt_steps)
-    return est, stats
+            ad.add(buffer_add, kpms_t, iq_t, alloc_d, _place(tp_t, ("batch",)))
+            ad.observe(t, float(np.sqrt(np.mean((est[:, t] - tp_t) ** 2))))
+    return est, ad.finish()
 
 
-def _online_estimate_fleet_ssm(episode, c: SSMConfig, params,
-                               ocfg: OnlineConfig, *,
+def _online_estimate_fleet_ssm(episode, estimator, ocfg: OnlineConfig, *,
                                serving: Optional[ServingMesh] = None,
                                tp_clip=TP_CLIP_MBPS, telemetry=None
                                ) -> tuple[np.ndarray, OnlineStats]:
@@ -673,6 +730,7 @@ def _online_estimate_fleet_ssm(episode, c: SSMConfig, params,
     and the recurrence's per-period decay forgets them at exp(dt*A);
     re-warming 30 columns per burst would reintroduce the O(WINDOW) cost
     this estimator exists to remove."""
+    c = estimator[0]
     if episode.kpms is None:
         raise ValueError("the recurrent estimator needs raw KPM reports: "
                          "generate the episode with include_kpms=True")
@@ -684,43 +742,19 @@ def _online_estimate_fleet_ssm(episode, c: SSMConfig, params,
     feats = episode_features(episode.kpms, episode.alloc_ratio,
                              episode.iq if c.include_iq else None)
     off = feats.shape[1] - t_steps - 1  # = WINDOW - 1, period 0's column
-    opt = AdamW(lr=ocfg.lr, weight_decay=ocfg.weight_decay,
-                clip_norm=ocfg.clip_norm)
-    opt_state = opt.init(params)
-    step_fn = online_step_program(c, opt, serving)
-    if serving is not None:
-        predict_fn = ssm_serving_program(c, serving)
-        params = replicate_params(serving, params)
-        ctx = sh.use_rules(serving.mesh, serving.rule_overrides())
-    else:
-        predict_fn = functools.partial(ssm_step, c)
-        ctx = contextlib.nullcontext()
-    mgr = (CheckpointManager(ocfg.ckpt_dir, keep=ocfg.ckpt_keep)
-           if ocfg.ckpt_dir else None)
-    buf = buffer_init(ocfg.capacity, c, serving=serving,
-                      quant=ocfg.ring_quant)
-    dstate = drift_init()
-    rng = np.random.default_rng(ocfg.seed)
-    key = jax.random.PRNGKey(ocfg.seed)
+    ad = OnlineAdapter(estimator, ocfg, t_steps, serving=serving,
+                       telemetry=telemetry)
     est = np.empty((n, t_steps))
-    rmse = np.empty(t_steps)
-    adapted = np.zeros(t_steps, bool)
-    train_loss: list = []
-    ckpt_steps: list = []
-    total_steps = 0
-    with ctx:
-        def place(x, axes):
-            return sh.put(jnp.asarray(x, F32), axes)
-
-        state = place(ssm_state_init(c, (n,)), STATE_AXES)
+    with ad.rules():
+        state = _place(ssm_state_init(c, (n,)), STATE_AXES)
         for col in range(off):  # warmup reports precede the first label
-            state, _ = predict_fn(params, state,
-                                  place(feats[:, col], ("batch", None)))
+            state, _ = ad.predict(ad.params, state,
+                                  _place(feats[:, col], ("batch", None)))
         for t in range(t_steps):
-            feats_t = place(feats[:, off + t], ("batch", None))
+            feats_t = _place(feats[:, off + t], ("batch", None))
             state_prev = state
             with telmod.stage("estimator_fwd"):
-                state, fc = predict_fn(params, state, feats_t)
+                state, fc = ad.predict(ad.params, state, feats_t)
                 fc = np.asarray(fc)
             # the monitor watches the served *current* estimate's error;
             # the controllers consume the policy-reduced forecasts
@@ -728,45 +762,7 @@ def _online_estimate_fleet_ssm(episode, c: SSMConfig, params,
             est[:, t] = np.clip(reduce_forecasts(c, fc),
                                 tp_clip[0], tp_clip[1])
             tp_t = episode.tp_mbps[:, t].astype(np.float32)
-            rmse[t] = float(np.sqrt(np.mean((cur - tp_t) ** 2)))
-            buf = buffer_add_ssm(buf, state_prev, feats_t,
-                                 place(tp_t, ("batch",)))
-            fill = buffer_count(buf)
-            dstate, fired = drift_step(ocfg.drift, dstate, rmse[t],
-                                       armed=fill >= ocfg.min_fill)
-            if telemetry is not None:
-                telemetry.drift(t, bool(fired), rmse[t],
-                                drift_threshold(ocfg.drift, dstate),
-                                n_triggers=int(dstate.n_triggers))
-            if fired:
-                data = buffer_data(buf)
-                burst = []
-                with telmod.stage("online_burst"):
-                    for _ in range(ocfg.steps):
-                        idx = jnp.asarray(rng.integers(0, fill, ocfg.batch),
-                                          I32)
-                        key, sub = jax.random.split(key)
-                        params, opt_state, loss = step_fn(params, opt_state,
-                                                          data, idx, sub)
-                        burst.append(float(loss))
-                    if serving is not None:
-                        with telmod.stage("weight_swap"):
-                            params = replicate_params(serving, params)
-                total_steps += ocfg.steps
-                train_loss.append(float(np.mean(burst)))
-                adapted[t] = True
-                if telemetry is not None:
-                    telemetry.burst(t, ocfg.steps, float(np.mean(burst)),
-                                    serving is not None)
-                if mgr is not None:
-                    mgr.save(dstate.n_triggers, params)  # async
-                    ckpt_steps.append(dstate.n_triggers)
-    if mgr is not None:
-        mgr.wait()
-    stats = OnlineStats(rmse=rmse, adapted=adapted,
-                        n_adaptations=int(adapted.sum()),
-                        train_steps=total_steps, train_loss=train_loss,
-                        buffer_fill=buffer_count(buf),
-                        threshold_mbps=drift_threshold(ocfg.drift, dstate),
-                        params=params, ckpt_steps=ckpt_steps)
-    return est, stats
+            ad.add(buffer_add_ssm, state_prev, feats_t,
+                   _place(tp_t, ("batch",)))
+            ad.observe(t, float(np.sqrt(np.mean((cur - tp_t) ** 2))))
+    return est, ad.finish()

@@ -31,8 +31,8 @@ Sessions come from ``repro.channel.scenarios.make_churn_schedule`` (the
 arrival/dwell realisation) plus an ``EpisodeBatch`` with one row per
 session (its channel life). ``simulate_fleet(churn=...)`` is the public
 entry; ``churn=None`` never enters this module (the engine's
-batch-synchronous path is the PR 5 program unchanged, pinned by
-``tests/test_sim_pool.py``).
+batch-synchronous path runs; a degenerate full pool is pinned equal to
+it by ``tests/test_sim_pool.py``).
 """
 from __future__ import annotations
 
@@ -123,12 +123,15 @@ def pool_programs(ewma_alpha: float, hysteresis_steps: int,
     handles distinct (capacity, sessions, horizon) shapes — churn moves
     the population, never the shapes, so the program never retraces).
 
-    ``telem`` (default None) swaps ``sweep`` for the telemetry variant:
-    the same scan additionally carries a ``TelemetryState``, folding each
-    period's masked metrics into it and logging per-lane EV_ADMIT /
-    aggregate EV_DEPART events into the device ring. ``telem=None``
-    returns the exact prior programs — the variant is a separate cache
-    entry, never a branch inside the default trace."""
+    ``sweep``'s scan body is ``admit``, the gather of each slot's
+    estimate, then the same serve-and-retire core ``serve_retire`` wraps.
+    With ``telem`` its carry also holds a ``TelemetryState`` (passed in as
+    ``ts0``), folding each period's masked metrics into it and logging
+    per-lane EV_ADMIT / aggregate EV_DEPART events into the device ring;
+    it returns ``((state, ts), (act, sid, age, split, share, lat, n_dep,
+    row))``. That is a Python branch at trace time: with ``telem=None``
+    ``ts`` and ``row`` are None and the program holds nothing of the
+    metric plane."""
     cfg = ControllerConfig(ewma_alpha, hysteresis_steps, fallback_split)
     step = functools.partial(controller_step, cfg=cfg)
     a_lanes = int(max_admits)
@@ -206,18 +209,23 @@ def pool_programs(ewma_alpha: float, hysteresis_steps: int,
         val = arr[jnp.clip(st.sid, 0, m - 1), jnp.clip(st.age, 0, el - 1)]
         return jnp.where(st.active, val.astype(F32), 0.0)
 
+    def _serve_retire(st: PoolState, tables, est_t, true, cell, dwell):
+        # one period's service of the admitted pool: the scheduler and the
+        # controllers over the active slots, then the dwell-out retirement
+        act, sid, age = st.active, st.sid, st.age
+        true_t = _gather_tp(st, true)
+        cell_t = cell[jnp.clip(sid, 0, cell.shape[0] - 1)]
+        st, split, share = _serve(st, tables, est_t, true_t, cell_t)
+        st, n_dep = _retire(st, dwell)
+        return st, (act, sid, age, split, share, n_dep), true_t
+
     @jax.jit
     def admit(st, t, ready_end_t, arrival_t, warm):
         return _admit(st, t, ready_end_t, arrival_t, warm)
 
     @jax.jit
     def serve_retire(st, tables, est_t, true, cell, dwell):
-        act, sid, age = st.active, st.sid, st.age
-        true_t = _gather_tp(st, true)
-        cell_t = cell[jnp.clip(sid, 0, cell.shape[0] - 1)]
-        st, split, share = _serve(st, tables, est_t, true_t, cell_t)
-        st, n_dep = _retire(st, dwell)
-        return st, (act, sid, age, split, share, n_dep)
+        return _serve_retire(st, tables, est_t, true, cell, dwell)[:2]
 
     @jax.jit
     def gather(st, wins, iq, alloc, true):
@@ -235,32 +243,9 @@ def pool_programs(ewma_alpha: float, hysteresis_steps: int,
         return (k, iq[sidc, agec], alloc[sidc],
                 _gather_tp(st, true), st.active)
 
-    if telem is None:
-        @jax.jit
-        def sweep(st0, tables, warm, est, true, cell, dwell, arrival_t,
-                  ready_end):
-            t_steps = ready_end.shape[0]
-
-            def body(st, xs):
-                t, ready_t = xs
-                st, lat = _admit(st, t, ready_t, arrival_t, warm)
-                act, sid, age = st.active, st.sid, st.age
-                est_t = _gather_tp(st, est)
-                true_t = _gather_tp(st, true)
-                cell_t = cell[jnp.clip(sid, 0, cell.shape[0] - 1)]
-                st, split, share = _serve(st, tables, est_t, true_t, cell_t)
-                st, n_dep = _retire(st, dwell)
-                return st, (act, sid, age, split, share, lat, n_dep)
-
-            return lax.scan(body, st0,
-                            (jnp.arange(t_steps, dtype=I32), ready_end))
-
-        return PoolPrograms(sweep=sweep, admit=admit,
-                            serve_retire=serve_retire, gather=gather)
-
     @jax.jit
-    def sweep_telem(st0, ts0, tables, warm, est, true, cell, dwell,
-                    arrival_t, ready_end, dconst, dbytes):
+    def sweep(st0, tables, warm, est, true, cell, dwell, arrival_t,
+              ready_end, ts0=None, dconst=None, dbytes=None):
         t_steps = ready_end.shape[0]
 
         def body(carry, xs):
@@ -268,31 +253,31 @@ def pool_programs(ewma_alpha: float, hysteresis_steps: int,
             t, ready_t = xs
             sid0 = st.next_arrival  # lanes admit sessions sid0, sid0+1, ...
             st, lat = _admit(st, t, ready_t, arrival_t, warm)
-            act, sid, age = st.active, st.sid, st.age
             est_t = _gather_tp(st, est)
-            true_t = _gather_tp(st, true)
-            cell_t = cell[jnp.clip(sid, 0, cell.shape[0] - 1)]
-            st, split, share = _serve(st, tables, est_t, true_t, cell_t)
-            st, n_dep = _retire(st, dwell)
-            with jax.named_scope("telemetry_step"):
-                eff = None
-                if sched is not None:
-                    # what split_metrics sees: PRB-scaled, floored
-                    eff = jnp.maximum(true_t * jnp.clip(share, 0.0, 1.0),
-                                      tpmod.PRB_FLOOR_MBPS)
-                ts, row = telmod.telemetry_step(
-                    telem, ts, period=t, split=split, est_tp=est_t,
-                    true_tp=true_t, eff_tp=eff, share=share, active=act,
-                    dconst=dconst, dbytes=dbytes,
-                    admit_sid=sid0 + jnp.arange(a_lanes, dtype=I32),
-                    admit_lat=lat, n_depart=n_dep)
+            st, ys, true_t = _serve_retire(st, tables, est_t, true, cell,
+                                           dwell)
+            act, sid, age, split, share, n_dep = ys
+            row = None
+            if telem is not None:
+                with jax.named_scope("telemetry_step"):
+                    eff = None
+                    if sched is not None:
+                        # what split_metrics sees: PRB-scaled, floored
+                        eff = jnp.maximum(true_t * jnp.clip(share, 0.0, 1.0),
+                                          tpmod.PRB_FLOOR_MBPS)
+                    ts, row = telmod.telemetry_step(
+                        telem, ts, period=t, split=split, est_tp=est_t,
+                        true_tp=true_t, eff_tp=eff, share=share, active=act,
+                        dconst=dconst, dbytes=dbytes,
+                        admit_sid=sid0 + jnp.arange(a_lanes, dtype=I32),
+                        admit_lat=lat, n_depart=n_dep)
             return (st, ts), (act, sid, age, split, share, lat, n_dep, row)
 
         return lax.scan(body, (st0, ts0),
                         (jnp.arange(t_steps, dtype=I32), ready_end))
 
-    return PoolPrograms(sweep=sweep_telem, admit=admit,
-                        serve_retire=serve_retire, gather=gather)
+    return PoolPrograms(sweep=sweep, admit=admit, serve_retire=serve_retire,
+                        gather=gather)
 
 
 @dataclasses.dataclass
@@ -405,7 +390,8 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
     ``FleetResult.telemetry``; ``telemetry=None`` (default) never builds
     it.
     """
-    from repro.sim.engine import FleetResult, estimate_fleet, split_metrics
+    from repro.sim.engine import (FleetResult, _host_telemetry,
+                                  estimate_fleet, split_metrics)
 
     _pool_validate(sessions, schedule, capacity, cell, sched)
     if online is not None and estimator is None:
@@ -419,25 +405,22 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
     programs = pool_programs(cfg.ewma_alpha, cfg.hysteresis_steps,
                              cfg.fallback_split, sched, int(n_cells),
                              int(schedule.max_admits), telem=telemetry)
-    tel = dconst = dbytes = None
-    if telemetry is not None:
-        tel = telmod.HostTelemetry(telemetry)
-        dconst = jnp.asarray(np.asarray(profile.d_ue(ue))
-                             + np.asarray(profile.d_ser(server)), F32)
-        dbytes = jnp.asarray(profile.data_bytes, F32)
+    tel, dconst, dbytes = _host_telemetry(telemetry, profile, ue, server)
 
     online_stats = None
     telem_rec = None
     if online is not None:
-        st0 = pool_init(capacity, warm_split)
         with telmod.ingest() as put:
             inputs = _pool_inputs(put, tables_np, warm_split, true_np, cell,
                                   schedule)
-        outs, est_tp, online_stats = _online_pool_run(
-            sessions, schedule, estimator, online, programs, st0, *inputs,
-            serving=serving, fused=fused, telemetry=tel, tel_dconst=dconst,
-            tel_dbytes=dbytes, tel_sched=sched is not None)
-        act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = outs
+        slots = _SlotLoop(programs, pool_init(capacity, warm_split),
+                          schedule.ready_end, inputs, tel, dconst, dbytes,
+                          sched is not None)
+        est_tp, online_stats = _online_pool_run(
+            sessions, estimator, online, slots, serving=serving, fused=fused,
+            telemetry=tel)
+        act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = (
+            slots.outputs())
         if tel is not None:
             telem_rec = tel.decode()
     else:
@@ -452,17 +435,15 @@ def simulate_pool(sessions: EpisodeBatch, schedule: ChurnSchedule, table,
                                            true_np, cell, schedule)
                 est_d = put(est_np, F32)
                 ready_d = put(schedule.ready_end, I32)
-            if telemetry is None:
-                _, ys = programs.sweep(st0, tables_d, warm_d, est_d, true_d,
-                                       cell_d, dwell_d, arrival_d, ready_d)
-            else:
-                (_, tel.ts), ys = programs.sweep(
-                    st0, tel.ts, tables_d, warm_d, est_d, true_d, cell_d,
-                    dwell_d, arrival_d, ready_d, dconst, dbytes)
-                ys, rows = ys[:7], ys[7]
-                telem_rec = tel.decode(rows)
+            (_, ts), ys = programs.sweep(
+                st0, tables_d, warm_d, est_d, true_d, cell_d, dwell_d,
+                arrival_d, ready_d, tel.ts if tel is not None else None,
+                dconst, dbytes)
+            if tel is not None:
+                tel.ts = ts
+                telem_rec = tel.decode(ys[7])
             act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts = (
-                np.asarray(y) for y in ys)
+                np.asarray(y) for y in ys[:7])
         est_tp = None  # gathered below from the per-session estimates
 
     with telmod.stage("gather"):
@@ -533,52 +514,95 @@ def _ssm_pool_gather(active, sid, age, feats, true):
     return f, tp
 
 
-def _online_pool_run(sessions, schedule, estimator, ocfg, programs, st0,
-                     tables_d, warm_d, true_d, cell_d, dwell_d, arrival_d,
-                     *, serving=None, tp_clip=TP_CLIP_MBPS,
-                     fused=False, telemetry=None, tel_dconst=None,
-                     tel_dbytes=None, tel_sched=False):
-    """The closed-loop arm of ``simulate_pool``: the same admit/serve/
-    retire step driven from a host loop so each period's estimator
-    forward runs with the *current* weights, only active slots' samples
-    are ring-ingested (``buffer_add_masked``), and drift-triggered
-    adaptation bursts run between periods exactly as in
-    ``repro.sim.online.online_estimate_fleet``.
+class _SlotLoop:
+    """The slot bookkeeping of the online pool loops: per period,
+    ``admit``, then (after the loop's estimate) ``serve`` through the
+    jitted ``serve_retire`` with the host telemetry update, and at the end
+    the stacked per-period ``outputs`` in the sweep's order."""
 
-    ``telemetry``: an optional ``telemetry.HostTelemetry`` — per period
-    one jitted metric update (masked to the live slots, with admission
-    lanes and departures) plus drift/burst/weight-swap events; the return
-    shapes are unchanged, the caller decodes the record."""
-    import contextlib
+    def __init__(self, programs: PoolPrograms, st0: PoolState, ready,
+                 inputs: tuple, telemetry=None, tel_dconst=None,
+                 tel_dbytes=None, tel_sched: bool = False):
+        self.programs, self.st = programs, st0
+        self.ready = np.asarray(ready, np.int64)
+        (self.tables_d, self.warm_d, self.true_d, self.cell_d, self.dwell_d,
+         self.arrival_d) = inputs
+        self.telemetry, self.tel_sched = telemetry, tel_sched
+        self.tel_dconst, self.tel_dbytes = tel_dconst, tel_dbytes
+        self.outs: list = []
+        self.lat_rows: list = []
+        self.sid0 = 0
 
-    from repro.checkpoint import CheckpointManager
-    from repro.dist import sharding as sh
-    from repro.estimator.ssm import SSMConfig
-    from repro.estimator.train import fwd
-    from repro.optim import AdamW
-    from repro.sim.online import (OnlineStats, buffer_add_masked,
-                                  buffer_count, buffer_data, buffer_init,
-                                  drift_init, drift_step, drift_threshold,
-                                  online_step_program)
-    from repro.sim.serving import replicate_params, serving_program
+    def admit(self, t: int) -> PoolState:
+        if self.telemetry is not None:
+            self.sid0 = int(self.st.next_arrival)
+        self.st, lat = self.programs.admit(
+            self.st, jnp.asarray(t, I32), jnp.asarray(int(self.ready[t]), I32),
+            self.arrival_d, self.warm_d)
+        self.lat_rows.append(np.asarray(lat))
+        return self.st
 
-    ecfg, params = estimator
-    if isinstance(ecfg, SSMConfig):
-        return _online_pool_run_ssm(
-            sessions, schedule, estimator, ocfg, programs, st0, tables_d,
-            warm_d, true_d, cell_d, dwell_d, arrival_d, serving=serving,
-            tp_clip=tp_clip, telemetry=telemetry, tel_dconst=tel_dconst,
-            tel_dbytes=tel_dbytes, tel_sched=tel_sched)
-    if sessions.iq is None:
-        raise ValueError(
-            "online adaptation needs IQ spectrograms: generate the episode "
-            "with include_iq=True")
-    s_slots = int(st0.active.shape[0])
+    def serve(self, t: int, est_col, tp_np) -> None:
+        self.st, ys = self.programs.serve_retire(
+            self.st, self.tables_d, jnp.asarray(est_col, F32), self.true_d,
+            self.cell_d, self.dwell_d)
+        o = [np.asarray(y) for y in ys]
+        self.outs.append(o)
+        if self.telemetry is not None:
+            eff = (np.maximum(tp_np * np.clip(o[4], 0.0, 1.0),
+                              tpmod.PRB_FLOOR_MBPS)
+                   if self.tel_sched else None)
+            lat = self.lat_rows[-1]
+            self.telemetry.update(
+                period=t, split=o[3], est=est_col, true=tp_np, share=o[4],
+                active=o[0], dconst=self.tel_dconst, dbytes=self.tel_dbytes,
+                eff=eff, admit_sid=self.sid0 + np.arange(lat.shape[0]),
+                admit_lat=lat, n_depart=o[5])
+
+    def outputs(self) -> tuple:
+        act, sid, age, split, share, dep = (
+            np.stack([o[i] for o in self.outs]) for i in range(6))
+        return act, sid, age, split, share, np.stack(self.lat_rows), dep
+
+
+def _check_ring_room(ocfg, slots: _SlotLoop) -> int:
+    s_slots = int(slots.st.active.shape[0])
     if int(ocfg.capacity) < s_slots:
         raise ValueError(
             f"OnlineConfig.capacity ({ocfg.capacity}) must cover the pool "
             f"capacity ({s_slots}) for masked ingestion")
-    t_steps = schedule.horizon
+    return s_slots
+
+
+def _online_pool_run(sessions, estimator, ocfg, slots: _SlotLoop, *,
+                     serving=None, tp_clip=TP_CLIP_MBPS, fused=False,
+                     telemetry=None):
+    """The closed-loop arm of ``simulate_pool``: the same admit/serve/
+    retire step driven from a host loop (``slots``) so each period's
+    estimator forward runs with the *current* weights, only active slots'
+    samples are ring-ingested (``buffer_add_masked``), and drift-triggered
+    adaptation bursts run between periods (``sim.online.OnlineAdapter``,
+    shared with ``online_estimate_fleet``). Returns the (S, T) estimates
+    and the ``OnlineStats``.
+
+    ``telemetry``: an optional ``telemetry.HostTelemetry`` — per period
+    one jitted metric update (masked to the live slots, with admission
+    lanes and departures; ``slots`` makes it) plus drift/burst/weight-swap
+    events; the caller decodes the record."""
+    from repro.dist import sharding as sh
+    from repro.estimator.ssm import SSMConfig
+    from repro.sim.online import OnlineAdapter, buffer_add_masked
+
+    if isinstance(estimator[0], SSMConfig):
+        return _online_pool_run_ssm(sessions, estimator, ocfg, slots,
+                                    serving=serving, tp_clip=tp_clip,
+                                    telemetry=telemetry)
+    if sessions.iq is None:
+        raise ValueError(
+            "online adaptation needs IQ spectrograms: generate the episode "
+            "with include_iq=True")
+    s_slots = _check_ring_room(ocfg, slots)
+    t_steps = len(slots.ready)
     if fused:
         # normalized trace instead of the WINDOW x window tensor; the
         # pool gather windows it per slot age (bit-identical elements)
@@ -593,124 +617,37 @@ def _online_pool_run(sessions, schedule, estimator, ocfg, programs, st0,
             sessions.kpm_windows(normalize=True).astype(np.float32))
     iq_d = jnp.asarray(np.asarray(sessions.iq, np.float32))
     alloc_d = jnp.asarray(sessions.alloc_ratio.astype(np.float32))
-    ready = np.asarray(schedule.ready_end, np.int64)
-    opt = AdamW(lr=ocfg.lr, weight_decay=ocfg.weight_decay,
-                clip_norm=ocfg.clip_norm)
-    opt_state = opt.init(params)
-    step_fn = online_step_program(ecfg, opt, serving)
-    if serving is not None:
-        predict_fn = serving_program(ecfg, serving)
-        params = replicate_params(serving, params)
-        ctx = sh.use_rules(serving.mesh, serving.rule_overrides())
-    else:
-        predict_fn = functools.partial(fwd, ecfg)
-        ctx = contextlib.nullcontext()
-    mgr = (CheckpointManager(ocfg.ckpt_dir, keep=ocfg.ckpt_keep)
-           if ocfg.ckpt_dir else None)
-    buf = buffer_init(ocfg.capacity, ecfg, serving=serving,
-                      quant=ocfg.ring_quant)
-    dstate = drift_init()
-    rng = np.random.default_rng(ocfg.seed)
-    key = jax.random.PRNGKey(ocfg.seed)
+    ad = OnlineAdapter(estimator, ocfg, t_steps, serving=serving,
+                       telemetry=telemetry)
     est_tp = np.zeros((s_slots, t_steps))
-    rmse = np.zeros(t_steps)
-    adapted = np.zeros(t_steps, bool)
-    train_loss: list = []
-    ckpt_steps: list = []
-    total_steps = 0
-    outs = []
-    lat_rows = []
-    st = st0
-    with ctx:
+    with ad.rules():
         for t in range(t_steps):
-            sid0 = int(st.next_arrival) if telemetry is not None else 0
-            st, lat = programs.admit(st, jnp.asarray(t, I32),
-                                     jnp.asarray(int(ready[t]), I32),
-                                     arrival_d, warm_d)
-            lat_rows.append(np.asarray(lat))
-            kpms_t, iq_t, alloc_t, tp_t, act_m = programs.gather(
-                st, wins_d, iq_d, alloc_d, true_d)
+            st = slots.admit(t)
+            kpms_t, iq_t, alloc_t, tp_t, act_m = slots.programs.gather(
+                st, wins_d, iq_d, alloc_d, slots.true_d)
             if serving is not None:
                 kpms_t = sh.put(kpms_t, ("batch", None, None))
                 iq_t = sh.put(iq_t, ("batch", None, None, None))
                 alloc_t = sh.put(alloc_t, ("batch",))
                 tp_t = sh.put(tp_t, ("batch",))
             with telmod.stage("estimator_fwd"):
-                raw = np.asarray(predict_fn(params, kpms_t, iq_t, alloc_t))
+                raw = np.asarray(ad.predict(ad.params, kpms_t, iq_t,
+                                            alloc_t))
             act_np = np.asarray(act_m)
             est_col = np.where(act_np,
                                np.clip(raw, tp_clip[0], tp_clip[1]), 0.0)
             est_tp[:, t] = est_col
             tp_np = np.asarray(tp_t)
             n_act = max(int(act_np.sum()), 1)
-            rmse[t] = float(np.sqrt(
-                np.sum(act_np * (est_col - tp_np) ** 2) / n_act))
-            buf = buffer_add_masked(buf, kpms_t, iq_t, alloc_t, tp_t, act_m)
-            fill = buffer_count(buf)
-            dstate, fired = drift_step(ocfg.drift, dstate, rmse[t],
-                                       armed=fill >= ocfg.min_fill)
-            if telemetry is not None:
-                telemetry.drift(t, bool(fired), rmse[t],
-                                drift_threshold(ocfg.drift, dstate),
-                                n_triggers=int(dstate.n_triggers))
-            if fired:
-                data = buffer_data(buf)
-                burst = []
-                with telmod.stage("online_burst"):
-                    for _ in range(ocfg.steps):
-                        idx = jnp.asarray(rng.integers(0, fill, ocfg.batch),
-                                          I32)
-                        key, sub = jax.random.split(key)
-                        params, opt_state, loss = step_fn(params, opt_state,
-                                                          data, idx, sub)
-                        burst.append(float(loss))
-                    if serving is not None:
-                        with telmod.stage("weight_swap"):
-                            params = replicate_params(serving, params)
-                total_steps += ocfg.steps
-                train_loss.append(float(np.mean(burst)))
-                adapted[t] = True
-                if telemetry is not None:
-                    telemetry.burst(t, ocfg.steps, float(np.mean(burst)),
-                                    serving is not None)
-                if mgr is not None:
-                    mgr.save(dstate.n_triggers, params)
-                    ckpt_steps.append(dstate.n_triggers)
-            st, ys = programs.serve_retire(
-                st, tables_d, jnp.asarray(est_col, F32), true_d, cell_d,
-                dwell_d)
-            outs.append([np.asarray(y) for y in ys])
-            if telemetry is not None:
-                o = outs[-1]
-                eff = (np.maximum(tp_np * np.clip(o[4], 0.0, 1.0),
-                                  tpmod.PRB_FLOOR_MBPS)
-                       if tel_sched else None)
-                telemetry.update(
-                    period=t, split=o[3], est=est_col, true=tp_np,
-                    share=o[4], active=o[0], dconst=tel_dconst,
-                    dbytes=tel_dbytes, eff=eff,
-                    admit_sid=sid0 + np.arange(lat_rows[-1].shape[0]),
-                    admit_lat=lat_rows[-1], n_depart=o[5])
-    if mgr is not None:
-        mgr.wait()
-    stats = OnlineStats(rmse=rmse, adapted=adapted,
-                        n_adaptations=int(adapted.sum()),
-                        train_steps=total_steps, train_loss=train_loss,
-                        buffer_fill=buffer_count(buf),
-                        threshold_mbps=drift_threshold(ocfg.drift, dstate),
-                        params=params, ckpt_steps=ckpt_steps)
-    act_ts, sid_ts, age_ts, split_ts, share_ts, dep_ts = (
-        np.stack([o[i] for o in outs]) for i in range(6))
-    lat_ts = np.stack(lat_rows)
-    return ((act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts),
-            est_tp, stats)
+            ad.add(buffer_add_masked, kpms_t, iq_t, alloc_t, tp_t, act_m)
+            ad.observe(t, float(np.sqrt(
+                np.sum(act_np * (est_col - tp_np) ** 2) / n_act)))
+            slots.serve(t, est_col, tp_np)
+    return est_tp, ad.finish()
 
 
-def _online_pool_run_ssm(sessions, schedule, estimator, ocfg, programs, st0,
-                         tables_d, warm_d, true_d, cell_d, dwell_d,
-                         arrival_d, *, serving=None, tp_clip=TP_CLIP_MBPS,
-                         telemetry=None, tel_dconst=None, tel_dbytes=None,
-                         tel_sched=False):
+def _online_pool_run_ssm(sessions, estimator, ocfg, slots: _SlotLoop, *,
+                         serving=None, tp_clip=TP_CLIP_MBPS, telemetry=None):
     """The recurrent closed-loop arm of ``simulate_pool``.
 
     Slots carry per-slot SSD states alongside the controller states. On
@@ -723,23 +660,14 @@ def _online_pool_run_ssm(sessions, schedule, estimator, ocfg, programs, st0,
     ``sim.online._online_estimate_fleet_ssm``). Each period is then one
     O(1) ``ssm_step`` over the capacity axis, masked ring-ingest of
     (pre-report state, report, label) events, and the shared drift/burst
-    machinery."""
-    import contextlib
-
-    from repro.checkpoint import CheckpointManager
+    machinery (``sim.online.OnlineAdapter``)."""
     from repro.dist import sharding as sh
     from repro.estimator.ssm import (episode_features, reduce_forecasts,
-                                     ssm_state_init, ssm_step,
-                                     ssm_warm_state)
-    from repro.optim import AdamW
-    from repro.sim.online import (OnlineStats, buffer_add_ssm, buffer_count,
-                                  buffer_data, buffer_init, drift_init,
-                                  drift_step, drift_threshold,
-                                  online_step_program)
-    from repro.sim.serving import (STATE_AXES, replicate_params,
-                                   ssm_serving_program)
+                                     ssm_state_init, ssm_warm_state)
+    from repro.sim.online import OnlineAdapter, _place, buffer_add_ssm
+    from repro.sim.serving import STATE_AXES
 
-    c, params = estimator
+    c = estimator[0]
     if sessions.kpms is None:
         raise ValueError("the recurrent estimator needs raw KPM reports: "
                          "generate sessions with include_kpms=True")
@@ -747,68 +675,32 @@ def _online_pool_run_ssm(sessions, schedule, estimator, ocfg, programs, st0,
         raise ValueError("SSMConfig(include_iq=True) needs spectrogram "
                          "snapshots: generate sessions with "
                          "include_iq=True")
-    s_slots = int(st0.active.shape[0])
-    if int(ocfg.capacity) < s_slots:
-        raise ValueError(
-            f"OnlineConfig.capacity ({ocfg.capacity}) must cover the pool "
-            f"capacity ({s_slots}) for masked ingestion")
-    t_steps = schedule.horizon
+    s_slots = _check_ring_room(ocfg, slots)
+    t_steps = len(slots.ready)
     feats_np = episode_features(sessions.kpms, sessions.alloc_ratio,
                                 sessions.iq if c.include_iq else None)
     feats_d = jnp.asarray(feats_np)  # (M, L + WINDOW, F)
     warm_prefix = jnp.asarray(feats_np[:, :WINDOW - 1])
-    ready = np.asarray(schedule.ready_end, np.int64)
-    opt = AdamW(lr=ocfg.lr, weight_decay=ocfg.weight_decay,
-                clip_norm=ocfg.clip_norm)
-    opt_state = opt.init(params)
-    step_fn = online_step_program(c, opt, serving)
-    if serving is not None:
-        predict_fn = ssm_serving_program(c, serving)
-        params = replicate_params(serving, params)
-        ctx = sh.use_rules(serving.mesh, serving.rule_overrides())
-    else:
-        predict_fn = functools.partial(ssm_step, c)
-        ctx = contextlib.nullcontext()
-    mgr = (CheckpointManager(ocfg.ckpt_dir, keep=ocfg.ckpt_keep)
-           if ocfg.ckpt_dir else None)
-    buf = buffer_init(ocfg.capacity, c, serving=serving,
-                      quant=ocfg.ring_quant)
-    dstate = drift_init()
-    rng = np.random.default_rng(ocfg.seed)
-    key = jax.random.PRNGKey(ocfg.seed)
+    ad = OnlineAdapter(estimator, ocfg, t_steps, serving=serving,
+                       telemetry=telemetry)
     est_tp = np.zeros((s_slots, t_steps))
-    rmse = np.zeros(t_steps)
-    adapted = np.zeros(t_steps, bool)
-    train_loss: list = []
-    ckpt_steps: list = []
-    total_steps = 0
-    outs = []
-    lat_rows = []
-    st = st0
-    with ctx:
-        def place(x, axes):
-            return sh.put(jnp.asarray(x, F32), axes)
-
-        warm_all = ssm_warm_state(c, params, warm_prefix)  # (M, ...)
-        slot_state = place(ssm_state_init(c, (s_slots,)), STATE_AXES)
+    with ad.rules():
+        warm_all = ssm_warm_state(c, ad.params, warm_prefix)  # (M, ...)
+        slot_state = _place(ssm_state_init(c, (s_slots,)), STATE_AXES)
         for t in range(t_steps):
-            sid0 = int(st.next_arrival) if telemetry is not None else 0
-            st, lat = programs.admit(st, jnp.asarray(t, I32),
-                                     jnp.asarray(int(ready[t]), I32),
-                                     arrival_d, warm_d)
-            lat_rows.append(np.asarray(lat))
+            st = slots.admit(t)
             fresh = st.active & (st.age == 0)  # admitted this period
             slot_state = _ssm_slot_reset(slot_state, warm_all, st.sid,
                                          fresh)
             feats_t, tp_t = _ssm_pool_gather(st.active, st.sid, st.age,
-                                             feats_d, true_d)
+                                             feats_d, slots.true_d)
             if serving is not None:
                 slot_state = sh.put(slot_state, STATE_AXES)
-                feats_t = place(feats_t, ("batch", None))
-                tp_t = place(tp_t, ("batch",))
+                feats_t = _place(feats_t, ("batch", None))
+                tp_t = _place(tp_t, ("batch",))
             state_prev = slot_state
             with telmod.stage("estimator_fwd"):
-                slot_state, fc = predict_fn(params, slot_state, feats_t)
+                slot_state, fc = ad.predict(ad.params, slot_state, feats_t)
                 fc = np.asarray(fc)
             act_np = np.asarray(st.active)
             cur = np.clip(fc[:, 0], tp_clip[0], tp_clip[1])
@@ -818,67 +710,11 @@ def _online_pool_run_ssm(sessions, schedule, estimator, ocfg, programs, st0,
             est_tp[:, t] = est_col
             tp_np = np.asarray(tp_t)
             n_act = max(int(act_np.sum()), 1)
-            rmse[t] = float(np.sqrt(
-                np.sum(act_np * (cur - tp_np) ** 2) / n_act))
-            buf = buffer_add_ssm(buf, state_prev, feats_t, tp_t,
-                                 mask=st.active)
-            fill = buffer_count(buf)
-            dstate, fired = drift_step(ocfg.drift, dstate, rmse[t],
-                                       armed=fill >= ocfg.min_fill)
-            if telemetry is not None:
-                telemetry.drift(t, bool(fired), rmse[t],
-                                drift_threshold(ocfg.drift, dstate),
-                                n_triggers=int(dstate.n_triggers))
-            if fired:
-                data = buffer_data(buf)
-                burst = []
-                with telmod.stage("online_burst"):
-                    for _ in range(ocfg.steps):
-                        idx = jnp.asarray(rng.integers(0, fill, ocfg.batch),
-                                          I32)
-                        key, sub = jax.random.split(key)
-                        params, opt_state, loss = step_fn(params, opt_state,
-                                                          data, idx, sub)
-                        burst.append(float(loss))
-                    if serving is not None:
-                        with telmod.stage("weight_swap"):
-                            params = replicate_params(serving, params)
+            ad.add(buffer_add_ssm, state_prev, feats_t, tp_t,
+                   mask=st.active)
+            if ad.observe(t, float(np.sqrt(
+                    np.sum(act_np * (cur - tp_np) ** 2) / n_act))):
                 # future admits warm with the weights that will serve them
-                warm_all = ssm_warm_state(c, params, warm_prefix)
-                total_steps += ocfg.steps
-                train_loss.append(float(np.mean(burst)))
-                adapted[t] = True
-                if telemetry is not None:
-                    telemetry.burst(t, ocfg.steps, float(np.mean(burst)),
-                                    serving is not None)
-                if mgr is not None:
-                    mgr.save(dstate.n_triggers, params)
-                    ckpt_steps.append(dstate.n_triggers)
-            st, ys = programs.serve_retire(
-                st, tables_d, jnp.asarray(est_col, F32), true_d, cell_d,
-                dwell_d)
-            outs.append([np.asarray(y) for y in ys])
-            if telemetry is not None:
-                o = outs[-1]
-                eff = (np.maximum(tp_np * np.clip(o[4], 0.0, 1.0),
-                                  tpmod.PRB_FLOOR_MBPS)
-                       if tel_sched else None)
-                telemetry.update(
-                    period=t, split=o[3], est=est_col, true=tp_np,
-                    share=o[4], active=o[0], dconst=tel_dconst,
-                    dbytes=tel_dbytes, eff=eff,
-                    admit_sid=sid0 + np.arange(lat_rows[-1].shape[0]),
-                    admit_lat=lat_rows[-1], n_depart=o[5])
-    if mgr is not None:
-        mgr.wait()
-    stats = OnlineStats(rmse=rmse, adapted=adapted,
-                        n_adaptations=int(adapted.sum()),
-                        train_steps=total_steps, train_loss=train_loss,
-                        buffer_fill=buffer_count(buf),
-                        threshold_mbps=drift_threshold(ocfg.drift, dstate),
-                        params=params, ckpt_steps=ckpt_steps)
-    act_ts, sid_ts, age_ts, split_ts, share_ts, dep_ts = (
-        np.stack([o[i] for o in outs]) for i in range(6))
-    lat_ts = np.stack(lat_rows)
-    return ((act_ts, sid_ts, age_ts, split_ts, share_ts, lat_ts, dep_ts),
-            est_tp, stats)
+                warm_all = ssm_warm_state(c, ad.params, warm_prefix)
+            slots.serve(t, est_col, tp_np)
+    return est_tp, ad.finish()

@@ -369,3 +369,72 @@ def test_online_config_frozen_and_hashable():
     b = dataclasses.replace(a, steps=7)
     assert hash(a) != () and a != b
     assert hash(DriftConfig()) == hash(DriftConfig())
+
+
+# ---------------------------------------------------- the shared adapter
+def _adapter_run(kind: str, loop: str, ckpt_dir: str):
+    """One tiny online ``simulate_fleet`` run of the ``kind`` estimator
+    (windowed or recurrent) on the ``loop`` path (batch or slot pool),
+    with a forced trigger, telemetry and checkpoints on."""
+    from repro.estimator.ssm import SSMConfig, init_ssm
+    from repro.sim import TelemetryConfig
+    rng = np.random.default_rng(41)
+    if kind == "ssm":
+        c = SSMConfig(n_heads=2, head_dim=4, state_dim=4, hidden=8)
+        est = (c, init_ssm(c, jax.random.PRNGKey(0)))
+    else:
+        est = tiny_estimator()
+    kw = {}
+    if loop == "pool":
+        T, capacity = 10, 6
+        schedule = sc.make_churn_schedule(
+            sc.ChurnConfig(arrival_rate=2.0, mean_dwell=4.0, max_dwell=6),
+            T, rng)
+        n, steps = schedule.n_sessions, schedule.max_dwell
+        kw = {"churn": schedule, "capacity": capacity}
+    else:
+        n, steps = 8, 10
+    names = np.asarray(sc.SCENARIOS)[np.arange(n) % len(sc.SCENARIOS)]
+    ep = sc.gen_episode_batch(names, steps, rng, n_sc=N_SC_TEST,
+                              include_iq=kind == "lstm")
+    ocfg = OnlineConfig(capacity=64, batch=8, steps=3, min_fill=8, seed=2,
+                        drift=DriftConfig(calibrate_periods=2,
+                                          threshold_mbps=0.0, patience=1,
+                                          cooldown=1),
+                        ckpt_dir=ckpt_dir, ckpt_keep=2)
+    prof = vgg_split_profile(FULL)
+    return ocfg, simulate_fleet(
+        ep, fig6_style_table(prof), prof, ControllerConfig(0.5, 2, 3),
+        estimator=est, online=ocfg, telemetry=TelemetryConfig(), **kw)
+
+
+@pytest.mark.parametrize("kind,loop", [("lstm", "batch"), ("lstm", "pool"),
+                                       ("ssm", "batch"), ("ssm", "pool")])
+def test_online_adapter_contract(kind, loop, tmp_path):
+    """All four online loops (windowed or recurrent, batch or pool) adapt
+    through the one ``OnlineAdapter``: ``ocfg.steps`` AdamW steps and one
+    checkpoint per trigger, one burst_start/burst_end event pair per
+    adapted period, and a seeded run that repeats exactly."""
+    from repro.checkpoint import CheckpointManager
+    ocfg, res = _adapter_run(kind, loop, str(tmp_path / "a"))
+    st = res.online
+    assert st.n_adaptations > 0
+    assert st.train_steps == st.n_adaptations * ocfg.steps
+    assert len(st.train_loss) == st.n_adaptations
+    assert st.ckpt_steps == list(range(1, st.n_adaptations + 1))
+    assert CheckpointManager(ocfg.ckpt_dir).latest() == st.n_adaptations
+    adapted = list(np.flatnonzero(st.adapted))
+    tl = res.telemetry
+    for name in ("drift_trigger", "burst_start", "burst_end"):
+        assert [e.period for e in tl.event_timeline([name])] == adapted
+    assert [e.arg for e in tl.event_timeline(["burst_end"])] == (
+        [ocfg.steps] * st.n_adaptations)
+    _, again = _adapter_run(kind, loop, str(tmp_path / "b"))
+    for a, b in ((res.splits, again.splits), (res.est_tp, again.est_tp),
+                 (st.rmse, again.online.rmse),
+                 (st.adapted, again.online.adapted),
+                 (st.train_loss, again.online.train_loss)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(st.params),
+                    jax.tree.leaves(again.online.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
